@@ -18,17 +18,8 @@ from __future__ import annotations
 
 import argparse
 import dataclasses
-import os
-import sys
 from pathlib import Path
 from typing import Dict, List, Optional, Tuple, Union
-
-# --real-training runs real jitted LM steps with one host device per
-# client (the CPU host-device trick); the device count must be forced
-# before jax is first imported, so it happens at module import, gated
-# on the flag actually being present.
-if "--real-training" in sys.argv and "XLA_FLAGS" not in os.environ:
-    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 
 from repro.common.config import (CloudConfig, ClientProfile, FLRunConfig,
                                  MarketConfig, ProviderConfig,
@@ -290,10 +281,10 @@ def main(argv=None):
                     help="comma-separated provider list for "
                          "--price-trace (default: aws)")
     ap.add_argument("--real-training", action="store_true",
-                    help="replace simulated epochs with real sharded "
-                         "jax_pallas LM steps (one host device per "
-                         "client) and bill update egress off the live "
-                         "param pytree")
+                    help="replace simulated epochs with real "
+                         "jax_pallas LM steps (one device per client; "
+                         "on CPU, host devices come from XLA_FLAGS) and "
+                         "bill update egress off the live param pytree")
     ap.add_argument("--quantize-updates", action="store_true",
                     help="with --real-training: int8-quantize client "
                          "updates (grad_quant codec) end to end — "
@@ -301,7 +292,7 @@ def main(argv=None):
     ap.add_argument("--rounds", type=int, default=2,
                     help="with --real-training: FL rounds (default 2)")
     ap.add_argument("--clients", type=int, default=2,
-                    help="with --real-training: client count, one host "
+                    help="with --real-training: client count, one "
                          "device each (default 2)")
     ap.add_argument("--assert-comm-win", action="store_true",
                     help="with --real-training: run fp32 AND quantized "
@@ -322,6 +313,8 @@ def main(argv=None):
         return "" if v is None else v
 
     if args.real_training:
+        from repro.common.compile_cache import enable_compile_cache
+        enable_compile_cache()
         row = next(r for r in ROWS
                    if r.dataset == (args.row or "MNIST"))
         recs = run_real_rows(row, rounds=args.rounds,

@@ -2,23 +2,20 @@
 'pod' of a device mesh hosts one FL client (DESIGN.md §2's TPU-idiomatic
 mapping of the paper's client/server pattern).
 
-On CPU this runs a (pod=2, data=1, model=1) toy mesh via the XLA host
-device trick; on a real multi-pod TPU deployment the same code runs the
+On CPU this runs a (pod=2, data=1, model=1) toy mesh on two host
+devices; on a real multi-pod TPU deployment the same code runs the
 production (2,16,16) mesh. Local steps touch no cross-pod axis; the
 synchronous FedAvg barrier is one weighted collective — optionally int8
-ring-compressed (4x less cross-pod traffic, EXPERIMENTS.md §Perf).
+ring-compressed (4x less cross-pod traffic).
 
-    PYTHONPATH=src python examples/mesh_fl_lm.py
+    XLA_FLAGS=--xla_force_host_platform_device_count=2 JAX_PLATFORMS=cpu \
+        PYTHONPATH=src python examples/mesh_fl_lm.py
 """
-import os
-os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=2")
-
 import jax
 import jax.numpy as jnp
 import numpy as np
 
 from repro import configs
-from repro.common import compat
 from repro.data.synthetic import token_stream
 from repro.fl import mesh_fl
 from repro.models import lm
@@ -29,7 +26,12 @@ LOCAL_STEPS = 4
 ROUNDS = 6
 B_LOCAL, SEQ = 8, 32
 
-mesh = jax.make_mesh((N_CLIENTS, 1, 1), ("pod", "data", "model"))
+if jax.device_count() < N_CLIENTS:
+    raise SystemExit(f"needs {N_CLIENTS} devices, found {jax.device_count()}: "
+                     "set XLA_FLAGS=--xla_force_host_platform_device_count=2")
+mesh = jax.make_mesh((N_CLIENTS, 1, 1), ("pod", "data", "model"),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 3,
+                     devices=jax.devices()[:N_CLIENTS])
 rules = R.make_rules("train")
 shard = R.ShardingCtx(mesh, rules)
 
@@ -47,7 +49,7 @@ round_step = jax.jit(round_step)
 streams = [token_stream(cfg.vocab_size, B_LOCAL, SEQ, seed=i)
            for i in range(N_CLIENTS)]
 
-with compat.set_mesh(mesh):
+with jax.set_mesh(mesh):
     for r in range(ROUNDS):
         batch = {
             "tokens": jnp.stack([

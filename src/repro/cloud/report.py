@@ -533,10 +533,12 @@ def _roofline_epoch_s(args) -> float:
     times the `launch.roofline` step-time estimate, scaled by
     `--time-scale` (the simulated-seconds-per-step-second knob real
     training calibrates with)."""
-    from repro.launch.roofline import estimate_step_time
-    step_s = estimate_step_time(args.roofline_flops, args.roofline_bytes,
-                                peak_flops=args.peak_flops,
-                                hbm_bw=args.hbm_bw)
+    from repro.launch.roofline import V5E, estimate_step_time
+    step_s = estimate_step_time(
+        args.roofline_flops, args.roofline_bytes,
+        peak_flops=(V5E.bf16_flops if args.peak_flops is None
+                    else args.peak_flops),
+        hbm_bw=V5E.hbm_bw if args.hbm_bw is None else args.hbm_bw)
     return args.steps_per_epoch * step_s * args.time_scale
 
 
@@ -709,9 +711,10 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
                         "(default 100)")
     p.add_argument("--peak-flops", type=float, default=None,
                    help="hardware peak FLOP/s override (default: the "
-                        "launch.mesh TPU constant)")
+                        "published TPU v5e peak in launch.roofline)")
     p.add_argument("--hbm-bw", type=float, default=None,
-                   help="hardware HBM bandwidth override, bytes/s")
+                   help="hardware HBM bandwidth override, bytes/s "
+                        "(default: the published TPU v5e bandwidth)")
     p.add_argument("--time-scale", type=float, default=1.0,
                    help="simulated seconds per roofline second "
                         "(default 1.0)")

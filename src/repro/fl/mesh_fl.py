@@ -27,7 +27,6 @@ import numpy as np
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro.common import compat
 from repro.models import lm
 from repro.sharding.rules import ShardingCtx
 
@@ -42,7 +41,10 @@ def fedavg_sync(params_stacked, weights):
     w = (weights / jnp.sum(weights)).astype(jnp.float32)
 
     def avg(p):
-        m = jnp.einsum("c...,c->...", p.astype(jnp.float32), w)
+        # elementwise weighting keeps the sum in fp32 (a dot over the
+        # client dim would run at the TPU's bf16 default precision)
+        wb = w.reshape((-1,) + (1,) * (p.ndim - 1))
+        m = jnp.sum(wb * p.astype(jnp.float32), axis=0)
         return jnp.broadcast_to(m[None].astype(p.dtype), p.shape)
 
     return jax.tree.map(avg, params_stacked)
@@ -101,7 +103,7 @@ def fedavg_sync_compressed(params_stacked, global_params, weights,
 
     def one_leaf(p_stk, g, spec_stk):
         delta = p_stk.astype(jnp.float32) - g.astype(jnp.float32)[None]
-        fn = compat.shard_map(
+        fn = jax.shard_map(
             ring_avg, mesh=mesh,
             in_specs=(spec_stk, P("pod")),
             out_specs=spec_stk,

@@ -1,23 +1,25 @@
-"""Real-training bridge: sharded jax_pallas client steps in the FL loop.
+"""Real-training bridge: client LM steps and the FedAvg barrier in the FL loop.
 
 `MeshTrainerHooks` is the `TrainerHooks` implementation that replaces
 hand-set epoch times and toy NumPy clients with the repo's real model
 stack: `models/lm.py` forward/backward (flash-attention path included)
-on a `(pod, data, model)` mesh where each pod hosts one FL client
-(`fl/mesh_fl.py`, DESIGN.md §2). On CPU the mesh runs via the XLA
-host-device trick — callers must set
-``XLA_FLAGS=--xla_force_host_platform_device_count=N`` *before* jax is
-imported (see `examples/mesh_fl_lm.py`; `benchmarks/table1.py
---real-training` and tests/test_training.py both do this).
+on a one-axis `pod` mesh with one FL client slot per device. Client
+stacks carry a leading client dim placed on `pod`
+(`NamedSharding(mesh, P("pod"))`), and both round programs are
+`shard_map`s over that axis: each device trains only its own slot, with
+no cross-device traffic, and the FedAvg barrier is one `psum` over
+`pod`. On a TPU host that is one client per chip; on CPU the devices
+come from ``XLA_FLAGS=--xla_force_host_platform_device_count=N``, set by
+the caller before jax is imported.
 
 Engine protocol mapping: the simulator calls `run_local(c, r)` at each
 client's simulated epoch-completion instant — the hooks only mark the
 client as a round participant there — and the actual jitted compute
 runs once per round inside `aggregate`, which local-trains every client
-slot in one vmapped scan and folds the *participants'* updates into the
-global model (non-participants get weight 0 and keep their previous
-momentum). Staleness folds into the FedAvg weights by the FedBuff
-1/sqrt(1+s) discount, so the async engine's reports are honored.
+slot and folds the *participants'* updates into the global model
+(non-participants get weight 0 and keep their previous momentum).
+Staleness folds into the FedAvg weights by the FedBuff 1/sqrt(1+s)
+discount, so the async engine's reports are honored.
 
 Quantized updates (`quantize=True`) round-trip every participant's
 per-leaf delta through the `kernels/grad_quant` int8 block codec before
@@ -28,8 +30,9 @@ one the real `aggregate()` consumes.
 Calibration (`calibrate` / `calibrated_profiles`) anchors simulated
 time to real compute: it wall-clocks the jitted round, cross-checks the
 measurement against a roofline estimate built from the compiled HLO's
-FLOP/byte counts and *measured host peaks*
-(`launch.roofline.estimate_step_time`), and rewrites
+FLOP/byte counts (`launch.roofline.estimate_step_time`) and the
+device's peaks — the published peaks of the chip's `device_kind`, or
+peaks measured on the host for CPU devices — and rewrites
 `ClientProfile.mean_epoch_s` from the measurement.
 """
 from __future__ import annotations
@@ -42,87 +45,54 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro import configs
-from repro.common import compat
-from repro.common.config import ClientProfile
+from repro.common.config import ClientProfile, ModelConfig
 from repro.comms.payload import UpdatePayload
 from repro.data.synthetic import token_stream
 from repro.fl.server import JaxTrainerHooks
 from repro.fl.types import TrainerHooks
 from repro.kernels.grad_quant import ops as gq
 from repro.models import lm
-from repro.sharding import rules as R
+
+_POD = P("pod")
 
 
 def _client_mesh(n_clients: int) -> jax.sharding.Mesh:
-    """A `(pod=n, data=1, model=1)` mesh over the first `n` host
-    devices — `jax.make_mesh` insists on using every device, so subsets
-    build the mesh directly."""
+    """A one-axis `pod` mesh over the first `n_clients` devices, one
+    client slot per device."""
     devices = jax.devices()
     if len(devices) < n_clients:
         raise ValueError(
-            f"need {n_clients} devices for {n_clients} clients, have "
-            f"{len(devices)}; set XLA_FLAGS="
-            f"--xla_force_host_platform_device_count={n_clients} "
-            f"before importing jax")
-    return jax.sharding.Mesh(
-        np.array(devices[:n_clients]).reshape(n_clients, 1, 1),
-        ("pod", "data", "model"))
+            f"{n_clients} clients need {n_clients} devices, one client "
+            f"slot each; found {len(devices)} {devices[0].platform} "
+            f"device(s) ({devices[0].device_kind})")
+    return jax.make_mesh((n_clients,), ("pod",),
+                         axis_types=(jax.sharding.AxisType.Auto,),
+                         devices=devices[:n_clients])
 
 
-class MeshTrainerHooks(TrainerHooks):
-    """Real sharded LM training behind the engine hook protocol (see
-    module docstring for the round mapping)."""
+def make_round_programs(cfg: ModelConfig, mesh: jax.sharding.Mesh, *,
+                        lr: float, quantize: bool, use_pallas: bool):
+    """The two jitted programs of one FL round on `mesh`'s `pod` axis.
 
-    def __init__(self, clients: Sequence[str],
-                 model: str = "phi3-mini-3.8b", smoke: bool = True,
-                 local_steps: int = 4, batch: int = 8, seq: int = 32,
-                 lr: float = 5e-3, quantize: bool = False,
-                 use_pallas: bool = False, seed: int = 0,
-                 weights: Optional[Dict[str, float]] = None):
-        self.clients = list(clients)
-        self.slot = {c: i for i, c in enumerate(self.clients)}
-        if len(self.slot) != len(self.clients):
-            raise ValueError("duplicate client names")
-        self.cfg = configs.get_config(model, smoke=smoke)
-        self.local_steps = local_steps
-        self.batch = batch
-        self.seq = seq
-        self.quantize = quantize
-        self.use_pallas = use_pallas
-        self._lr = lr
-        n = len(self.clients)
-        self.mesh = _client_mesh(n)
-        self.shard = R.ShardingCtx(self.mesh, R.make_rules("train"))
-        params = lm.init_params(self.cfg, jax.random.PRNGKey(seed))
-        from repro.fl import mesh_fl
-        self.params_stk = mesh_fl.stack_params_for_clients(params, n)
-        self.mu_stk = jax.tree.map(
-            lambda p: jnp.zeros(p.shape, jnp.float32), self.params_stk)
-        self._base_w = np.array(
-            [float((weights or {}).get(c, 1.0)) for c in self.clients])
-        self._streams = [token_stream(self.cfg.vocab_size, batch, seq,
-                                      seed=seed + 17 * i)
-                         for i in range(n)]
-        self._participants: Dict[str, int] = {}   # client -> last round
-        self.losses: List[dict] = []              # per-aggregation record
-        self._local_fn = jax.jit(jax.vmap(self._local_train))
-        self._avg_fn = jax.jit(self._weighted_delta_avg)
+    `local(params, mu, batches) -> (params, mu, losses)` runs
+    `batches`' leading-step count of SGD-momentum steps on every client
+    slot. `fedavg(new_p, old_p, new_mu, old_mu, w) -> (params, mu)` is
+    the barrier: each slot's fp32 delta against its pre-round params
+    (every slot holds the global model), optionally round-tripped
+    through the int8 codec, weighted by `w` and summed over `pod`; slots
+    with weight 0 keep their old momentum. `new_p`/`new_mu` are donated.
+    Every argument and result is a client stack placed on `pod`.
+    """
+    stk = NamedSharding(mesh, _POD)
 
-    # ------------------------------------------------------------------
-    # Jitted pieces.
-    # ------------------------------------------------------------------
-    def _local_train(self, params, mu, client_batches):
-        """`local_steps` SGD-momentum steps on one client slot (the
-        same inline optimizer as `mesh_fl.make_fl_round_step`)."""
-        cfg, lr = self.cfg, self._lr
-
+    def local_train(params, mu, client_batches):
         def step(carry, batch):
             p, m = carry
             loss, g = jax.value_and_grad(
-                lambda pp: lm.loss_fn(pp, cfg, batch,
-                                      shard=self.shard))(p)
+                lambda pp: lm.loss_fn(pp, cfg, batch))(p)
             m = jax.tree.map(
                 lambda mi, gi: 0.9 * mi + gi.astype(jnp.float32), m, g)
             p = jax.tree.map(
@@ -134,80 +104,114 @@ class MeshTrainerHooks(TrainerHooks):
                                         client_batches)
         return params, mu, losses
 
-    @staticmethod
-    def _weighted_delta_avg(deltas, global_p, w):
-        """Weighted mean of per-client fp32 deltas, applied to the
-        global model and re-broadcast to every client slot."""
-        wn = w / jnp.maximum(jnp.sum(w), 1e-12)
+    def codec(d):
+        q, s = gq.quantize(d, use_pallas=use_pallas)
+        return gq.dequantize(q, s, d.shape, jnp.float32,
+                             use_pallas=use_pallas)
 
-        def one(d, g):
-            avg = jnp.einsum("c...,c->...", d, wn)
-            new_g = g.astype(jnp.float32) + avg
-            return jnp.broadcast_to(new_g[None].astype(g.dtype),
-                                    d.shape)
+    def fedavg(new_p, old_p, new_mu, old_mu, w):
+        # per device: slot-local arrays with a leading client dim
+        wn = w / jnp.maximum(lax.psum(jnp.sum(w), "pod"), 1e-12)
+        bcast = lambda x, ref: x.reshape((-1,) + (1,) * (ref.ndim - 1))
 
-        return jax.tree.map(one, deltas, global_p)
+        def leaf(n, o):
+            d = n.astype(jnp.float32) - o.astype(jnp.float32)
+            if quantize:
+                d = jax.vmap(codec)(d)
+            # elementwise weighting keeps the sum in fp32 (a dot over
+            # the client dim would run at the TPU's bf16 default)
+            avg = lax.psum(jnp.sum(bcast(wn, d) * d, axis=0), "pod")
+            return (o.astype(jnp.float32) + avg).astype(o.dtype)
 
-    def _quant_roundtrip(self, deltas):
-        """Round-trip every participant's per-leaf delta through the
-        grad_quant int8 block codec — the aggregated update is built
-        from exactly the payload the comms subsystem bills."""
-        def one_leaf(d):
-            per_client = d.shape[1:]
+        keep = w > 0
+        mu = jax.tree.map(lambda n, o: jnp.where(bcast(keep, n), n, o),
+                          new_mu, old_mu)
+        return jax.tree.map(leaf, new_p, old_p), mu
 
-            def rt(x):
-                q, s = gq.quantize(x, use_pallas=self.use_pallas)
-                return gq.dequantize(q, s, per_client, jnp.float32,
-                                     use_pallas=self.use_pallas)
+    # check_vma=False: the Pallas kernels' out_shapes carry no
+    # varying-mesh-axis annotation, which the check would demand
+    local = jax.jit(
+        jax.shard_map(jax.vmap(local_train), mesh=mesh,
+                      in_specs=(_POD, _POD, _POD),
+                      out_specs=(_POD, _POD, _POD), check_vma=False),
+        in_shardings=(stk, stk, stk), out_shardings=(stk, stk, stk))
+    avg = jax.jit(
+        jax.shard_map(fedavg, mesh=mesh, in_specs=(_POD,) * 5,
+                      out_specs=(_POD, _POD), check_vma=False),
+        in_shardings=(stk,) * 5, out_shardings=(stk, stk),
+        donate_argnums=(0, 2))
+    return local, avg
 
-            return jax.vmap(rt)(d)
 
-        return jax.tree.map(one_leaf, deltas)
+class MeshTrainerHooks(TrainerHooks):
+    """Real LM training behind the engine hook protocol (see module
+    docstring for the round mapping). `cfg` defaults to the phi3-mini
+    smoke config."""
+
+    def __init__(self, clients: Sequence[str],
+                 cfg: Optional[ModelConfig] = None,
+                 local_steps: int = 4, batch: int = 8, seq: int = 32,
+                 lr: float = 5e-3, quantize: bool = False,
+                 use_pallas: bool = False, seed: int = 0,
+                 weights: Optional[Dict[str, float]] = None):
+        self.clients = list(clients)
+        self.slot = {c: i for i, c in enumerate(self.clients)}
+        if len(self.slot) != len(self.clients):
+            raise ValueError("duplicate client names")
+        self.cfg = cfg or configs.get_config("phi3-mini-3.8b", smoke=True)
+        self.local_steps = local_steps
+        n = len(self.clients)
+        self.mesh = _client_mesh(n)
+        self.stacked = NamedSharding(self.mesh, _POD)
+        self._local_fn, self._avg_fn = make_round_programs(
+            self.cfg, self.mesh, lr=lr, quantize=quantize,
+            use_pallas=use_pallas)
+
+        def init(key):
+            p = lm.init_params(self.cfg, key)
+            stk = jax.tree.map(
+                lambda x: jnp.broadcast_to(x[None], (n,) + x.shape), p)
+            return stk, jax.tree.map(
+                lambda x: jnp.zeros(x.shape, jnp.float32), stk)
+
+        self.params_stk, self.mu_stk = jax.jit(
+            init, out_shardings=(self.stacked, self.stacked))(
+                jax.random.PRNGKey(seed))
+        self._base_w = np.array(
+            [float((weights or {}).get(c, 1.0)) for c in self.clients])
+        self._streams = [token_stream(self.cfg.vocab_size, batch, seq,
+                                      seed=seed + 17 * i)
+                         for i in range(n)]
+        self._participants: Dict[str, int] = {}   # client -> last round
+        self.losses: List[dict] = []              # per-aggregation record
 
     # ------------------------------------------------------------------
     # TrainerHooks protocol.
     # ------------------------------------------------------------------
     def run_local(self, client: str, round_idx: int) -> None:
         """Mark the client's round-`round_idx` update as produced; the
-        jitted compute itself batches into `aggregate` (one vmapped
-        round per aggregation, every pod training in parallel)."""
+        jitted compute itself batches into `aggregate` (one round
+        program per aggregation, every device training in parallel)."""
         if client not in self.slot:
             raise KeyError(f"unknown client {client!r}")
         self._participants[client] = round_idx
 
     def aggregate(self, participants: List[str], round_idx: int,
                   staleness: Optional[Dict[str, int]] = None) -> None:
-        """Run the real round: vmapped local training on every slot,
-        then fold the participants' (optionally int8-round-tripped)
-        deltas into the global model with staleness-discounted FedAvg
-        weights."""
+        """Run the real round: local training on every slot, then fold
+        the participants' (optionally int8-round-tripped) deltas into
+        the global model with staleness-discounted FedAvg weights."""
         live = [c for c in participants if c in self._participants]
         if not live:
             return
         stale = staleness or {}
-        batches = self._next_batches()
-        new_p, new_mu, losses = self._run_round(batches)
-        mask = np.zeros(len(self.clients))
+        w = np.zeros(len(self.clients), np.float32)
         for c in set(live):
-            mask[self.slot[c]] = (
+            w[self.slot[c]] = (
                 self._base_w[self.slot[c]]
                 * JaxTrainerHooks.staleness_discount(stale.get(c, 0)))
-        w = jnp.asarray(mask, jnp.float32)
-        global_p = jax.tree.map(lambda p: p[0], self.params_stk)
-        deltas = jax.tree.map(
-            lambda np_, g: np_.astype(jnp.float32)
-            - g.astype(jnp.float32)[None], new_p, global_p)
-        if self.quantize:
-            deltas = self._quant_roundtrip(deltas)
-        with compat.set_mesh(self.mesh):
-            self.params_stk = self._avg_fn(deltas, global_p, w)
-        # only participants actually trained: the rest keep their
-        # momentum (their slot's compute was masked out of the average)
-        keep = jnp.asarray(mask > 0)
-        self.mu_stk = jax.tree.map(
-            lambda new, old: jnp.where(
-                keep.reshape((-1,) + (1,) * (old.ndim - 1)), new, old),
-            new_mu, self.mu_stk)
+        new_p, new_mu, losses = self.local_round(self.next_batches())
+        self.params_stk, self.mu_stk = self.fedavg(new_p, new_mu, w)
         losses = np.asarray(losses)
         self.losses.append({
             "round": round_idx,
@@ -218,24 +222,39 @@ class MeshTrainerHooks(TrainerHooks):
 
     def update_payload(self, quantized: bool = False) -> UpdatePayload:
         """Byte-exact size of one client's update: the global param
-        pytree in the requested wire format."""
-        global_p = jax.tree.map(lambda p: p[0], self.params_stk)
-        return UpdatePayload.from_tree(global_p, quantized=quantized)
+        pytree in the requested wire format (sized from shapes alone)."""
+        slot = jax.tree.map(
+            lambda p: jax.ShapeDtypeStruct(p.shape[1:], p.dtype),
+            self.params_stk)
+        return UpdatePayload.from_tree(slot, quantized=quantized)
 
     # ------------------------------------------------------------------
     # Round execution + measurement.
     # ------------------------------------------------------------------
-    def _next_batches(self):
+    def next_batches(self):
+        """The next `local_steps` batches of every client stream,
+        stacked `(clients, local_steps, batch, seq)` and placed one
+        client per device."""
         stacked = {"tokens": [], "labels": []}
         for s in self._streams:
             rows = [next(s) for _ in range(self.local_steps)]
             stacked["tokens"].append(np.stack([r["tokens"] for r in rows]))
             stacked["labels"].append(np.stack([r["labels"] for r in rows]))
-        return {k: jnp.asarray(np.stack(v)) for k, v in stacked.items()}
+        return jax.device_put({k: np.stack(v) for k, v in stacked.items()},
+                              self.stacked)
 
-    def _run_round(self, batches):
-        with compat.set_mesh(self.mesh):
-            return self._local_fn(self.params_stk, self.mu_stk, batches)
+    def local_round(self, batches):
+        """Local training of every slot from the current state, which
+        is not advanced: `(params, mu, losses)` client stacks."""
+        return self._local_fn(self.params_stk, self.mu_stk, batches)
+
+    def fedavg(self, new_p, new_mu, w):
+        """The FedAvg barrier over the round's results (`new_p` and
+        `new_mu` are consumed) with per-slot weights `w`: the new
+        `(params, mu)` stacks. State is not advanced."""
+        return self._avg_fn(new_p, self.params_stk, new_mu, self.mu_stk,
+                            jax.device_put(np.asarray(w, np.float32),
+                                           self.stacked))
 
     def global_params(self):
         """The current global model (slot 0 of the stacked params — all
@@ -252,30 +271,28 @@ class MeshTrainerHooks(TrainerHooks):
         """Wall-clock one jitted round (local training of every slot)
         on held-out batches, after `warmup` compile/warm runs. State is
         not advanced."""
-        batches = self._next_batches()
+        batches = self.next_batches()
         for _ in range(max(warmup, 1)):
-            out = self._run_round(batches)
-            jax.block_until_ready(out)
+            jax.block_until_ready(self.local_round(batches))
         t0 = time.perf_counter()
         for _ in range(max(iters, 1)):
-            out = self._run_round(batches)
-            jax.block_until_ready(out)
+            jax.block_until_ready(self.local_round(batches))
         return (time.perf_counter() - t0) / max(iters, 1)
 
 
 # ---------------------------------------------------------------------------
 # Calibration: measured step time -> simulated ClientProfile epoch times,
-# cross-checked against a measured-peak roofline estimate.
+# cross-checked against a roofline estimate.
 # ---------------------------------------------------------------------------
 @dataclasses.dataclass(frozen=True)
 class StepCalibration:
     """One calibration measurement and its roofline cross-check."""
     measured_round_s: float      # wall-clock of one jitted round
-    roofline_round_s: float      # estimate from HLO counts + host peaks
-    flops: float                 # total HLO FLOPs across all host devices
-    bytes_accessed: float        # total HLO HBM-proxy bytes
-    host_peak_flops: float       # measured matmul throughput (FLOP/s)
-    host_bw: float               # measured memory bandwidth (bytes/s)
+    roofline_round_s: float      # estimate from HLO counts + peaks
+    flops: float                 # HLO FLOPs the estimate charges
+    bytes_accessed: float        # HLO HBM-proxy bytes it charges
+    peak_flops: float            # FLOP/s: published chip / measured host
+    peak_bw: float               # bytes/s: published chip / measured host
 
     @property
     def ratio(self) -> float:
@@ -318,28 +335,39 @@ def _measure_host_peaks(dim: int = 256, iters: int = 8):
 def calibrate(hooks: MeshTrainerHooks, warmup: int = 1,
               iters: int = 2) -> StepCalibration:
     """Measure one round's wall-clock and cross-check it against the
-    roofline estimate built from the compiled module's HLO FLOP/byte
-    counts and measured host peaks. Host devices share one physical
-    CPU, so per-device counts scale by the device (client) count and
-    the terms combine serially (`combine="sum"`)."""
+    roofline estimate built from the compiled per-device module's HLO
+    FLOP/byte counts.
+
+    On accelerator chips the clients run in parallel, one per chip, so
+    the per-chip counts are charged once against the published peaks
+    of the chip's `device_kind` (an unknown kind raises), with the
+    classic overlapping bound (`combine="max"`). CPU host devices share
+    one physical CPU, so there the counts scale by the client count and
+    meet measured host peaks serially (`combine="sum"`)."""
     from repro.launch import hlo_analysis as HA
-    from repro.launch.roofline import estimate_step_time
+    from repro.launch.roofline import chip_peaks, estimate_step_time
 
     measured = hooks.measure_round_s(warmup=warmup, iters=iters)
-    batches = hooks._next_batches()
-    with compat.set_mesh(hooks.mesh):
-        compiled = hooks._local_fn.lower(
-            hooks.params_stk, hooks.mu_stk, batches).compile()
+    compiled = hooks._local_fn.lower(
+        hooks.params_stk, hooks.mu_stk, hooks.next_batches()).compile()
     hc = HA.analyze_hlo_text(compiled.as_text())
-    n = len(hooks.clients)
-    flops, nbytes = hc.flops * n, hc.hbm_bytes * n
-    peak_flops, bw = _measure_host_peaks()
+    device = hooks.mesh.devices.flat[0]
+    if device.platform == "cpu":
+        n = len(hooks.clients)
+        flops, nbytes = hc.flops * n, hc.hbm_bytes * n
+        peak_flops, bw = _measure_host_peaks()
+        combine = "sum"
+    else:
+        flops, nbytes = hc.flops, hc.hbm_bytes
+        peaks = chip_peaks(device.device_kind)
+        peak_flops, bw = peaks.bf16_flops, peaks.hbm_bw
+        combine = "max"
     roofline = estimate_step_time(flops, nbytes, peak_flops=peak_flops,
-                                  hbm_bw=bw, combine="sum")
+                                  hbm_bw=bw, combine=combine)
     return StepCalibration(measured_round_s=measured,
                            roofline_round_s=roofline, flops=flops,
-                           bytes_accessed=nbytes,
-                           host_peak_flops=peak_flops, host_bw=bw)
+                           bytes_accessed=nbytes, peak_flops=peak_flops,
+                           peak_bw=bw)
 
 
 def calibrated_profiles(profiles: Sequence[ClientProfile],

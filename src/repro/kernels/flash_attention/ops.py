@@ -1,8 +1,8 @@
 """Jitted public wrapper for the flash-attention kernel.
 
 Accepts model-layout tensors (B, S, N, H) (kv pre-expanded to N heads by
-the attention layer) and dispatches to the Pallas kernel (TPU) or the
-interpret-mode kernel body (CPU validation).
+the attention layer) and runs the Pallas kernel, compiled for the TPU
+unless `interpret=True` asks for the interpreter (CPU tests).
 
 Differentiable: forward runs the Pallas kernel; the VJP recomputes
 attention with the reference path (flash-backward kernels are a logged
@@ -62,9 +62,7 @@ _fa.defvjp(_fa_fwd, _fa_bwd)
                                              "block_q", "block_k",
                                              "interpret"))
 def flash_attention(q, k, v, *, causal=True, window=None, softcap=None,
-                    block_q=512, block_k=512, interpret=None):
+                    block_q=512, block_k=512, interpret=False):
     """q, k, v: (B, S|T, N, H) -> (B, S, N, H)."""
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
     return _fa(q, k, v, causal, window, softcap, block_q, block_k,
                interpret)

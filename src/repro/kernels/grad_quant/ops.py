@@ -1,5 +1,9 @@
 """Jitted wrappers: quantize/dequantize arbitrary-shaped tensors by
-flattening to padded (nb, BLOCK) rows."""
+flattening to padded (nb, BLOCK) rows.
+
+`use_pallas` selects the Pallas kernel, compiled for the TPU unless
+`interpret=True` asks for the interpreter (CPU tests); otherwise the
+pure-jnp reference codec runs."""
 from __future__ import annotations
 
 import functools
@@ -22,24 +26,19 @@ def _pad_rows(x):
 
 
 @functools.partial(jax.jit, static_argnames=("use_pallas", "interpret"))
-def quantize(x, use_pallas=False, interpret=None):
-    """x: any shape -> (q int8 (nb,BLOCK), scales (nb,1), meta n)."""
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
-    x2d, n = _pad_rows(x)
+def quantize(x, use_pallas=False, interpret=False):
+    """x: any shape -> (q int8 (nb, BLOCK), scales f32 (nb, 1))."""
+    x2d, _ = _pad_rows(x)
     if use_pallas:
-        q, s = K.quantize_blocks(x2d, interpret=interpret)
-    else:
-        q, s = R.quantize_blocks_ref(x2d)
-    return q, s
+        return K.quantize_blocks(x2d, interpret=interpret)
+    return R.quantize_blocks_ref(x2d)
 
 
 @functools.partial(jax.jit, static_argnames=("shape", "dtype", "use_pallas",
                                              "interpret"))
 def dequantize(q, scales, shape, dtype=jnp.float32, use_pallas=False,
-               interpret=None):
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+               interpret=False):
+    """(q, scales) from `quantize` -> an array of `shape` and `dtype`."""
     if use_pallas:
         x2d = K.dequantize_blocks(q, scales, dtype, interpret=interpret)
     else:

@@ -1,18 +1,19 @@
 """Pallas TPU kernel for the RG-LRU linear recurrence (Griffin /
 RecurrentGemma): h_t = a_t * h_{t-1} + b_t, per channel.
 
-Same TPU-native structure as the SSD kernel: the sequence is chunked,
+Same TPU-native structure as the SSD kernel: the sequence is chunked and
 the inter-chunk carry lives in VMEM scratch across the sequential chunk
-grid dimension, and the intra-chunk recurrence is computed in parallel
-form with a masked log-space decay matrix (the per-channel analogue of
-SSD's segsum):
+grid dimension. Inside a chunk the recurrence is a Hillis-Steele scan
+over the affine maps h -> a h + b: log2(Q) steps, each composing every
+row with the row 2^k above it (a sublane rotate), so the chunk is
+computed in registers with no cumsum and no (Q, Q, W) decay tensor.
+After the scan row t holds (prod a, accumulated b) over rows <= t, and
 
-  h_t = exp(cum_t) * h_in + sum_{j<=t} exp(cum_t - cum_j) * b_j
+  h_t = A_t * h_in + B_t.
 
 Grid: (batch, w_blocks, n_chunks), chunks innermost.
-BlockSpec tiles (VMEM): a, b, h: (1, Q, WB); carry scratch (WB,).
-Q=128, WB=128 -> decay matrix tile (Q,Q) per channel slice stays MXU
-aligned and the working set is ~8MB.
+BlockSpec tiles (VMEM): a, b, h: (1, Q, WB); carry scratch (1, WB).
+Q=128, WB=128: each operand is 16 vregs of f32.
 """
 from __future__ import annotations
 
@@ -31,24 +32,21 @@ def _rglru_kernel(loga_ref, b_ref, h_ref, carry_scr, *, chunk):
     def _init():
         carry_scr[...] = jnp.zeros_like(carry_scr)
 
-    la = loga_ref[0].astype(jnp.float32)       # (Q, WB) log decay
-    b = b_ref[0].astype(jnp.float32)           # (Q, WB)
-    cum = jnp.cumsum(la, axis=0)               # inclusive
+    a = jnp.exp(loga_ref[0].astype(jnp.float32))     # (Q, WB)
+    b = b_ref[0].astype(jnp.float32)                 # (Q, WB)
+    row = jax.lax.broadcasted_iota(jnp.int32, a.shape, 0)
+    k = 1
+    while k < chunk:
+        has_prev = row >= k
+        a_prev = jnp.where(has_prev, pltpu.roll(a, k, 0), 1.0)
+        b_prev = jnp.where(has_prev, pltpu.roll(b, k, 0), 0.0)
+        b = a * b_prev + b
+        a = a * a_prev
+        k *= 2
 
-    # intra-chunk: decay[i,j] = exp(cum_i - cum_j) for i >= j (the step-j
-    # input is already post-decay of step j, so the diagonal is 1).
-    qi = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
-    qj = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
-    mask = qi >= qj
-    # per-channel decay matrix applied via einsum over j
-    diff = cum[:, None, :] - cum[None, :, :]   # (Q, Q, WB)
-    decay = jnp.where(mask[..., None], jnp.exp(diff), 0.0)
-    h_intra = jnp.einsum("ijw,jw->iw", decay, b)
-
-    carry = carry_scr[...]                     # (WB,)
-    h = h_intra + jnp.exp(cum) * carry[None, :]
+    h = a * carry_scr[...] + b
     h_ref[0] = h.astype(h_ref.dtype)
-    carry_scr[...] = h[-1].astype(jnp.float32)
+    carry_scr[...] = h[chunk - 1:chunk, :]
 
 
 def rglru_scan_b(log_a, b, *, chunk=128, block_w=128, interpret=False):
@@ -71,6 +69,6 @@ def rglru_scan_b(log_a, b, *, chunk=128, block_w=128, interpret=False):
         out_specs=pl.BlockSpec((1, chunk, block_w),
                                lambda bi, wi, ci: (bi, ci, wi)),
         out_shape=jax.ShapeDtypeStruct((B, S, W), b.dtype),
-        scratch_shapes=[pltpu.VMEM((block_w,), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((1, block_w), jnp.float32)],
         interpret=interpret,
     )(log_a, b)

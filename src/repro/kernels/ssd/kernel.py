@@ -8,16 +8,20 @@ Grid: (batch*heads, n_chunks) — chunks innermost, executed in order on a
 TPU core, so the (head_dim, d_state) state tile never leaves VMEM between
 chunks (the GPU formulation materializes all chunk states in HBM and runs
 a separate scan kernel; on TPU the sequential grid makes that round trip
-unnecessary — this is the TPU-native adaptation noted in DESIGN.md).
+unnecessary).
 
 BlockSpec tiling per grid step (VMEM):
-  x    : (1, Q, P)      inputs (already dt-scaled)
-  la   : (1, Q)         dt * A  (log decay)
-  B, C : (1, Q, N)      input/output projections
-  y    : (1, Q, P)      output
-  state: (P, N) f32     scratch, persists across chunks
+  x      : (1, Q, P)      inputs (already dt-scaled)
+  la_row : (1, 1, Q)      dt * A (log decay), lanes
+  la_col : (1, Q, 1)      the same values, sublanes
+  B, C   : (1, Q, N)      input/output projections
+  y      : (1, Q, P)      output
+  state  : (P, N) f32     scratch, persists across chunks
 Q=chunk (256), P=head_dim (64), N=d_state (128): ~0.5MB — VMEM-friendly,
-and the (Q,Q) intra-chunk score tile is 256x256 (MXU-aligned).
+and the (Q,Q) intra-chunk score tile is 256x256 (MXU-aligned). The log
+decay comes in both orientations because the chunk's inclusive cumulative
+sum is needed as a row and as a column; each is a triangular masked sum
+of the other orientation (Mosaic has no cumsum), exact in f32.
 """
 from __future__ import annotations
 
@@ -28,8 +32,11 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+_HI = jax.lax.Precision.HIGHEST
 
-def _ssd_kernel(x_ref, la_ref, b_ref, c_ref, y_ref, state_scr, *, chunk):
+
+def _ssd_kernel(x_ref, lar_ref, lac_ref, b_ref, c_ref, y_ref, state_scr, *,
+                chunk):
     ic = pl.program_id(1)
 
     @pl.when(ic == 0)
@@ -37,34 +44,42 @@ def _ssd_kernel(x_ref, la_ref, b_ref, c_ref, y_ref, state_scr, *, chunk):
         state_scr[...] = jnp.zeros_like(state_scr)
 
     x = x_ref[0].astype(jnp.float32)          # (Q, P)
-    la = la_ref[0].astype(jnp.float32)        # (Q,)
+    la_row = lar_ref[0].astype(jnp.float32)   # (1, Q)
+    la_col = lac_ref[0].astype(jnp.float32)   # (Q, 1)
     Bm = b_ref[0].astype(jnp.float32)         # (Q, N)
     Cm = c_ref[0].astype(jnp.float32)         # (Q, N)
 
-    la_cs = jnp.cumsum(la)                    # inclusive (Q,)
-    # intra-chunk: L[i,j] = exp(la_cs[i] - la_cs[j]) for i >= j
-    diff = la_cs[:, None] - la_cs[None, :]
     qi = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
     qj = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
-    L = jnp.where(qi >= qj, jnp.exp(diff), 0.0)
+    lower = qi >= qj
+    # inclusive cumulative sums: cs_col[i] = sum_{j<=i} la[j], as a
+    # column (from the lane layout) and as a row (from the sublane one)
+    cs_col = jnp.sum(jnp.where(lower, la_row, 0.0), axis=1, keepdims=True)
+    cs_row = jnp.sum(jnp.where(qi <= qj, la_col, 0.0), axis=0,
+                     keepdims=True)
+    total = jnp.sum(la_row, axis=1, keepdims=True)        # (1, 1)
+
+    # intra-chunk: L[i,j] = exp(cs[i] - cs[j]) for i >= j
+    L = jnp.exp(jnp.where(lower, cs_col - cs_row, -jnp.inf))
     att = jax.lax.dot_general(Cm, Bm, (((1,), (1,)), ((), ())),
+                              precision=_HI,
                               preferred_element_type=jnp.float32)  # (Q,Q)
     y = jax.lax.dot_general(att * L, x, (((1,), (0,)), ((), ())),
+                            precision=_HI,
                             preferred_element_type=jnp.float32)    # (Q,P)
-    # contribution of the carried state: C_i . state * exp(la_cs_i)
+    # contribution of the carried state: C_i . state * exp(cs_i)
     state = state_scr[...]                     # (P, N)
-    y += jnp.exp(la_cs)[:, None] * jax.lax.dot_general(
-        Cm, state, (((1,), (1,)), ((), ())),
+    y += jnp.exp(cs_col) * jax.lax.dot_general(
+        Cm, state, (((1,), (1,)), ((), ())), precision=_HI,
         preferred_element_type=jnp.float32)
     y_ref[0] = y.astype(y_ref.dtype)
 
     # state update: state' = a_chunk * state + sum_j decay_j * x_j B_j^T
-    decay_end = jnp.exp(la_cs[-1] - la_cs)     # (Q,)
-    xw = x * decay_end[:, None]                # (Q, P)
+    xw = x * jnp.exp(total - cs_col)           # (Q, P)
     new_state = jax.lax.dot_general(
-        xw, Bm, (((0,), (0,)), ((), ())),
+        xw, Bm, (((0,), (0,)), ((), ())), precision=_HI,
         preferred_element_type=jnp.float32)    # (P, N)
-    state_scr[...] = jnp.exp(la_cs[-1]) * state + new_state
+    state_scr[...] = jnp.exp(total) * state + new_state
 
 
 def ssd_bh(x, la, Bm, Cm, *, chunk=256, interpret=False):
@@ -81,7 +96,8 @@ def ssd_bh(x, la, Bm, Cm, *, chunk=256, interpret=False):
         grid=(BH, nc),
         in_specs=[
             pl.BlockSpec((1, chunk, P), lambda b, c: (b, c, 0)),
-            pl.BlockSpec((1, chunk), lambda b, c: (b, c)),
+            pl.BlockSpec((1, 1, chunk), lambda b, c: (b, 0, c)),
+            pl.BlockSpec((1, chunk, 1), lambda b, c: (b, c, 0)),
             pl.BlockSpec((1, chunk, N), lambda b, c: (b, c, 0)),
             pl.BlockSpec((1, chunk, N), lambda b, c: (b, c, 0)),
         ],
@@ -89,4 +105,4 @@ def ssd_bh(x, la, Bm, Cm, *, chunk=256, interpret=False):
         out_shape=jax.ShapeDtypeStruct((BH, S, P), x.dtype),
         scratch_shapes=[pltpu.VMEM((P, N), jnp.float32)],
         interpret=interpret,
-    )(x, la, Bm, Cm)
+    )(x, la[:, None, :], la[:, :, None], Bm, Cm)

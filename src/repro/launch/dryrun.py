@@ -1,12 +1,12 @@
-import os
-os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "")
-                           + " --xla_force_host_platform_device_count=512")
 """Multi-pod dry-run: AOT-lower + compile every (arch x shape) cell on the
 production meshes and extract memory / cost / collective analyses.
 
-MUST be run as its own process (the XLA flag above is locked in at jax
-init): ``PYTHONPATH=src python -m repro.launch.dryrun --arch all --shape
-all --mesh both``.
+Runs on 512 host devices, which the XLA flag must provide before jax
+starts, so run it as its own process::
+
+    XLA_FLAGS=--xla_force_host_platform_device_count=512 JAX_PLATFORMS=cpu \
+        PYTHONPATH=src python -m repro.launch.dryrun --arch all \
+        --shape all --mesh both
 
 Also lowers the FL-in-the-mesh round step (the paper-representative
 program) when ``--fl-round`` is passed.
@@ -21,7 +21,6 @@ import jax
 import jax.numpy as jnp
 
 from repro import configs
-from repro.common import compat
 from repro.common.config import SHAPES
 from repro.configs.shapes import input_specs
 from repro.launch import mesh as M
@@ -46,7 +45,7 @@ def lower_cell(arch: str, shape_name: str, mesh, rule_overrides=None):
     jitted, _ = ST.jit_step_for(cfg, shape, mesh,
                                 rule_overrides=rule_overrides)
     specs = input_specs(cfg, shape)
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         if shape.kind == "train":
             lowered = jitted.lower(lm.abstract_params(cfg),
                                    abstract_opt_state(cfg), specs["batch"])
@@ -152,7 +151,7 @@ def run_fl_round(mesh, mesh_name: str, arch: str = "phi3-mini-3.8b",
     jitted = jax.jit(step, in_shardings=(pshard, mushard, bshard, wshard),
                      out_shardings=(pshard, mushard, wshard))
     t0 = time.time()
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         lowered = jitted.lower(params_stk, mu_stk, batches, weights)
         compiled = lowered.compile()
     t1 = time.time()
@@ -215,7 +214,7 @@ def run_fl_agg(mesh, mesh_name: str, arch: str = "phi3-mini-3.8b",
                          out_shardings=pshard)
         args_ = (params_stk, weights)
     t0 = time.time()
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         lowered = jitted.lower(*args_)
         compiled = lowered.compile()
     t1 = time.time()
@@ -245,8 +244,11 @@ def main(argv=None):
     ap.add_argument("--fail-fast", action="store_true")
     args = ap.parse_args(argv)
 
-    assert jax.device_count() >= 512, (
-        "dry-run needs the 512 fake CPU devices; run as its own process")
+    if jax.device_count() < 512:
+        raise SystemExit(
+            f"dry-run needs 512 devices, found {jax.device_count()}: set "
+            "XLA_FLAGS=--xla_force_host_platform_device_count=512 before "
+            "starting it")
 
     meshes = []
     if args.mesh in ("single", "both"):

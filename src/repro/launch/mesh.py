@@ -4,29 +4,20 @@ Single pod : (data=16, model=16)      = 256 chips (TPU v5e pod slice)
 Multi-pod  : (pod=2, data=16, model=16) = 512 chips
 
 Defined as functions (never module-level constants) so importing this
-module cannot touch jax device state — the dry-run sets
-XLA_FLAGS=--xla_force_host_platform_device_count=512 *before* jax init,
-everything else sees the real 1-CPU environment.
+module cannot touch jax device state. Axes are `Auto`: the model code
+places arrays with sharding constraints, which an `Explicit` axis (the
+`jax.make_mesh` default since JAX 0.9) would turn into assertions.
 """
 from __future__ import annotations
 
 import jax
 
-# TPU v5e hardware constants used by the roofline analysis (per chip).
-PEAK_FLOPS_BF16 = 197e12          # FLOP/s
-HBM_BW = 819e9                    # bytes/s
-ICI_BW_PER_LINK = 50e9            # bytes/s per link (~ one ICI direction)
-
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
-
-
-def make_cpu_mesh():
-    """1-device mesh for smoke tests (sharding code paths stay live)."""
-    return jax.make_mesh((1, 1), ("data", "model"))
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def mesh_chips(mesh) -> int:

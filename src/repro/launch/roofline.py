@@ -18,7 +18,35 @@ import dataclasses
 import re
 from typing import Dict, List, Optional, Tuple
 
-from repro.launch import mesh as M
+
+@dataclasses.dataclass(frozen=True)
+class ChipPeaks:
+    """Published per-chip peaks of one accelerator kind."""
+    bf16_flops: float            # FLOP/s
+    hbm_bw: float                # bytes/s
+    hbm_bytes: float             # device memory
+    ici_bw_per_link: float       # bytes/s per link, one direction
+
+
+# Keyed by `jax.Device.device_kind`. TPU v5e: Google Cloud documentation,
+# "TPU v5e" — 197 TFLOP/s bf16, 16 GB HBM at 819 GB/s, 1,600 Gbit/s of
+# chip-to-chip interconnect (200 GB/s over 4 links).
+CHIP_PEAKS: Dict[str, ChipPeaks] = {
+    "TPU v5 lite": ChipPeaks(bf16_flops=197e12, hbm_bw=819e9,
+                             hbm_bytes=16e9, ici_bw_per_link=50e9),
+}
+# the chip the dry-run's production meshes are made of
+V5E = CHIP_PEAKS["TPU v5 lite"]
+
+
+def chip_peaks(device_kind: str) -> ChipPeaks:
+    """The published peaks of `device_kind`; a kind not in the table is
+    an error, never a default."""
+    try:
+        return CHIP_PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(f"no published peaks for device kind "
+                         f"{device_kind!r}; known: {sorted(CHIP_PEAKS)}")
 
 _DTYPE_BYTES = {
     "f64": 8, "f32": 4, "f16": 2, "bf16": 2,
@@ -137,9 +165,9 @@ def analyze(compiled, *, n_chips: int, scan_trip_count: int,
     flops = hc.flops
     nbytes = hc.hbm_bytes
 
-    compute_s = flops / M.PEAK_FLOPS_BF16
-    memory_s = nbytes / M.HBM_BW
-    collective_s = hc.total_collective_bytes / M.ICI_BW_PER_LINK
+    compute_s = flops / V5E.bf16_flops
+    memory_s = nbytes / V5E.hbm_bw
+    collective_s = hc.total_collective_bytes / V5E.ici_bw_per_link
     terms = {"compute": compute_s, "memory": memory_s,
              "collective": collective_s}
     dominant = max(terms, key=terms.get)
@@ -159,22 +187,17 @@ def analyze(compiled, *, n_chips: int, scan_trip_count: int,
 
 
 def estimate_step_time(flops: float, bytes_accessed: float, *,
-                       peak_flops: Optional[float] = None,
-                       hbm_bw: Optional[float] = None,
+                       peak_flops: float, hbm_bw: float,
                        combine: str = "max") -> float:
     """Roofline wall-clock estimate for one step from its FLOP and byte
-    counts, against overridable hardware peaks.
+    counts, against the given hardware peaks.
 
-    Defaults use the TPU constants in `launch.mesh` (the dry-run
-    analysis above); the training calibrator
-    (`repro.fl.training.calibrate`) passes *measured* host peaks
-    instead, so the same formula cross-checks a CPU-measured step time.
+    The training calibrator (`repro.fl.training.calibrate`) passes a
+    chip's published peaks (`chip_peaks`) or peaks measured on the host.
     `combine="max"` is the classic roofline bound (terms overlap);
     `"sum"` models a serial host where compute and memory traffic share
     one pipe — the right shape for the CPU host-device trick.
     """
-    peak_flops = M.PEAK_FLOPS_BF16 if peak_flops is None else peak_flops
-    hbm_bw = M.HBM_BW if hbm_bw is None else hbm_bw
     compute_s = flops / peak_flops
     memory_s = bytes_accessed / hbm_bw
     if combine == "sum":

@@ -19,6 +19,7 @@ import numpy as np
 from repro import configs
 from repro.checkpoint.ckpt import Checkpointer
 from repro.checkpoint.store import FileStore
+from repro.common.compile_cache import enable_compile_cache
 from repro.data.synthetic import token_stream
 from repro.launch import steps as ST
 from repro.models import lm
@@ -38,6 +39,7 @@ def main(argv=None):
     ap.add_argument("--ckpt-every", type=int, default=20)
     ap.add_argument("--log-every", type=int, default=10)
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = configs.get_config(args.arch, smoke=args.smoke)
     train_step, opt = ST.make_train_step(cfg, lr=args.lr)

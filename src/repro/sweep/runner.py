@@ -9,9 +9,10 @@ fans cells over a `multiprocessing` pool and `Pool.map` preserves
 submission order, so the parallel result list is byte-identical to the
 serial one.
 
-The pool uses the "spawn"-safe module-level worker (`run_cell` itself);
-workers re-import this module rather than inheriting interpreter state,
-so nothing about the parent process can leak into a cell.
+The pool uses the "spawn"-safe module-level worker (`run_cell` itself).
+It forks, which is only safe because a cell never starts a JAX backend:
+a sweep process holds no accelerator and no JAX runtime threads to fork
+(tests/test_sweep.py pins that).
 """
 from __future__ import annotations
 

@@ -1,0 +1,127 @@
+"""Compiles for a described TPU v5e: the four Pallas kernels at published
+widths and the one-chip FL round program of the phi3-mini cut.
+
+Nothing runs: the TPU compiler, which is installed without a chip,
+refuses what the chip would refuse (block shapes off the (8, 128) tiling,
+ops Mosaic cannot lower, a program that does not fit HBM). The topology
+is described inside a module fixture, never at import, so every test
+worker collects the same tests and only the worker running this file
+loads the TPU library.
+"""
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import NamedSharding, PartitionSpec as P, \
+    SingleDeviceSharding
+
+# the HBM share the one-chip cut must leave free
+MIN_FREE_BYTES = 2e9
+
+
+@pytest.fixture(scope="module")
+def topo():
+    """A described v5e:2x2 host, with the persistent compilation cache
+    off: a TPU compile cached here could not be read back without a
+    chip."""
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    enabled = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    try:
+        desc = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:       # no TPU compiler to describe it with
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", enabled)
+    cc.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _shape(sharding, shape, dtype):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def _compiled_text(fn, *args):
+    return jax.jit(fn).lower(*args).compile().as_text()
+
+
+def test_flash_attention_phi3_width(one_chip):
+    from repro.kernels.flash_attention.ops import flash_attention
+    qkv = _shape(one_chip, (1, 2048, 32, 96), jnp.bfloat16)
+    text = _compiled_text(lambda q, k, v: flash_attention(q, k, v),
+                          qkv, qkv, qkv)
+    assert "tpu_custom_call" in text
+
+
+def test_ssd_mamba2_width(one_chip):
+    from repro.kernels.ssd.ops import ssd
+    b, s, h, p, n = 1, 2048, 64, 64, 128
+    text = _compiled_text(
+        lambda x, la, B, C: ssd(x, la, B, C, chunk=256)[0],
+        _shape(one_chip, (b, s, h, p), jnp.bfloat16),
+        _shape(one_chip, (b, s, h), jnp.float32),
+        _shape(one_chip, (b, s, h, n), jnp.bfloat16),
+        _shape(one_chip, (b, s, h, n), jnp.bfloat16))
+    assert "tpu_custom_call" in text
+
+
+def test_rglru_recurrentgemma_width(one_chip):
+    from repro.kernels.rglru.ops import rglru_scan
+    x = _shape(one_chip, (1, 2048, 2560), jnp.float32)
+    text = _compiled_text(
+        lambda la, b: rglru_scan(la, b, chunk=128, block_w=128), x, x)
+    assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("shape", [
+    (3072, 8192),        # one phi3-mini MLP projection: many full tiles
+    (32064, 3072),       # the embedding: a partial last tile
+    (3072,),             # a norm scale: fewer rows than one tile
+])
+def test_grad_quant_phi3_leaves(one_chip, shape):
+    from repro.kernels.grad_quant import ops as gq
+    x = _shape(one_chip, shape, jnp.float32)
+    text = _compiled_text(
+        lambda x: gq.dequantize(*gq.quantize(x, use_pallas=True), shape,
+                                jnp.float32, use_pallas=True), x)
+    assert text.count("tpu_custom_call") >= 2
+
+
+def test_phi3_one_chip_round_fits(topo):
+    """Both round programs of the one-chip cut compile, run the Pallas
+    kernels, and leave the HBM share the cut was sized for."""
+    from repro.configs.phi3_mini_3p8b import (ONE_CHIP, ONE_CHIP_BATCH,
+                                              ONE_CHIP_LOCAL_STEPS,
+                                              ONE_CHIP_SEQ)
+    from repro.fl.training import make_round_programs
+    from repro.launch.roofline import V5E
+    from repro.models import lm
+    mesh = jax.sharding.Mesh(np.array(topo.devices[:1]), ("pod",),
+                             axis_types=(jax.sharding.AxisType.Auto,))
+    stk = NamedSharding(mesh, P("pod"))
+    local, fedavg = make_round_programs(ONE_CHIP, mesh, lr=5e-3,
+                                        quantize=True, use_pallas=True)
+    params = jax.tree.map(lambda a: _shape(stk, (1,) + a.shape, a.dtype),
+                          lm.abstract_params(ONE_CHIP))
+    mu = jax.tree.map(lambda a: _shape(stk, a.shape, jnp.float32), params)
+    batches = {k: _shape(stk, (1, ONE_CHIP_LOCAL_STEPS, ONE_CHIP_BATCH,
+                               ONE_CHIP_SEQ), jnp.int32)
+               for k in ("tokens", "labels")}
+    w = _shape(stk, (1,), jnp.float32)
+    for compiled in (local.lower(params, mu, batches).compile(),
+                     fedavg.lower(params, params, mu, mu, w).compile()):
+        assert "tpu_custom_call" in compiled.as_text()
+        m = compiled.memory_analysis()
+        used = (m.argument_size_in_bytes + m.output_size_in_bytes
+                + m.temp_size_in_bytes - m.alias_size_in_bytes)
+        assert used <= V5E.hbm_bytes - MIN_FREE_BYTES, used

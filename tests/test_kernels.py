@@ -4,6 +4,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.flash_attention.ops import flash_attention
 from repro.kernels.flash_attention.ref import reference_attention
@@ -138,7 +139,7 @@ class TestGradQuant:
     def test_pallas_matches_ref(self, shape):
         rng = np.random.RandomState(sum(shape))
         x = jnp.asarray(rng.randn(*shape) * 0.01, jnp.float32)
-        qp, sp = quantize(x, use_pallas=True)
+        qp, sp = quantize(x, use_pallas=True, interpret=True)
         qr, sr = quantize(x, use_pallas=False)
         assert jnp.array_equal(qp, qr)
         np.testing.assert_allclose(np.asarray(sp), np.asarray(sr),
@@ -148,18 +149,35 @@ class TestGradQuant:
     def test_roundtrip_error_bound(self, dtype):
         rng = np.random.RandomState(5)
         x = jnp.asarray(rng.randn(4, 3333), dtype)
-        q, s = quantize(x, use_pallas=True)
+        q, s = quantize(x, use_pallas=True, interpret=True)
         xd = dequantize(q, s, (4, 3333), dtype=jnp.float32,
-                        use_pallas=True)
+                        use_pallas=True, interpret=True)
         amax = float(jnp.max(jnp.abs(x.astype(jnp.float32))))
         # symmetric int8: error <= scale/2 <= amax/254 per block
         err = float(jnp.max(jnp.abs(xd - x.astype(jnp.float32))))
         assert err <= amax / 127.0 + 1e-6
 
+    @pytest.mark.parametrize("nb", [QK.ROWS + 44, 2 * QK.ROWS])
+    def test_multi_tile_grid(self, nb):
+        """More block rows than one tile holds, with and without a
+        partial last tile: every row's codes and scale match the
+        reference, and dequantize inverts them."""
+        rng = np.random.RandomState(nb)
+        x2d = jnp.asarray(rng.randn(nb, 2048) * 0.02, jnp.float32)
+        qp, sp = QK.quantize_blocks(x2d, interpret=True)
+        qr, sr = QR.quantize_blocks_ref(x2d)
+        assert qp.shape == (nb, 2048) and sp.shape == (nb, 1)
+        assert jnp.array_equal(qp, qr)
+        np.testing.assert_allclose(np.asarray(sp), np.asarray(sr),
+                                   rtol=1e-6)
+        xd = QK.dequantize_blocks(qp, sp, interpret=True)
+        np.testing.assert_array_equal(
+            np.asarray(xd), np.asarray(QR.dequantize_blocks_ref(qp, sp)))
+
     def test_zero_tensor(self):
         x = jnp.zeros((2, 100), jnp.float32)
-        q, s = quantize(x, use_pallas=True)
-        xd = dequantize(q, s, (2, 100), use_pallas=True)
+        q, s = quantize(x, use_pallas=True, interpret=True)
+        xd = dequantize(q, s, (2, 100), use_pallas=True, interpret=True)
         assert float(jnp.max(jnp.abs(xd))) == 0.0
 
 
@@ -189,7 +207,7 @@ class TestFlashAttentionGrad:
 
     def test_model_trains_with_pallas_attention(self):
         """End-to-end: a smoke transformer takes a grad step with
-        cfg.use_pallas=True (interpret mode on CPU)."""
+        cfg.use_pallas=True (TPU interpret mode on CPU)."""
         import dataclasses
         from repro import configs
         from repro.models import lm
@@ -201,8 +219,9 @@ class TestFlashAttentionGrad:
                      rng.randint(0, cfg.vocab_size, (2, 16)), jnp.int32),
                  "labels": jnp.asarray(
                      rng.randint(0, cfg.vocab_size, (2, 16)), jnp.int32)}
-        loss, grads = jax.value_and_grad(
-            lambda p: lm.loss_fn(p, cfg, batch))(params)
+        with pltpu.force_tpu_interpret_mode():
+            loss, grads = jax.value_and_grad(
+                lambda p: lm.loss_fn(p, cfg, batch))(params)
         assert bool(jnp.isfinite(loss))
         gn = sum(float(jnp.sum(jnp.abs(g)))
                  for g in jax.tree.leaves(grads))
@@ -236,7 +255,8 @@ class TestRGLRU:
         toks = jnp.asarray(rng.randint(0, cfg.vocab_size, (2, 16)),
                            jnp.int32)
         ref_cfg = dataclasses.replace(cfg, use_pallas=False)
-        lo_k, _ = lm.forward(params, cfg, toks)
+        with pltpu.force_tpu_interpret_mode():
+            lo_k, _ = lm.forward(params, cfg, toks)
         lo_r, _ = lm.forward(params, ref_cfg, toks)
         err = float(jnp.max(jnp.abs(lo_k - lo_r)))
         assert err < 2e-3, err

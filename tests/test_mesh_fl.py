@@ -1,28 +1,25 @@
 """FL-in-the-mesh tests (2 fake pods on CPU): plain vs compressed FedAvg
 agreement, sync-barrier invariants, and the FL round step."""
-import os
-
-# 2 host devices so a real (pod=2) mesh exists; must precede jax import.
-os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=2")
-
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from repro import configs
-from repro.common import compat
 from repro.fl import mesh_fl
 from repro.models import lm
 from repro.sharding import rules as R
 
-pytestmark = pytest.mark.skipif(
-    jax.device_count() < 2, reason="needs >=2 devices (XLA_FLAGS set too "
-    "late — another test initialized jax first)")
 
-
-def make_mesh():
-    return jax.make_mesh((2, 1, 1), ("pod", "data", "model"))
+@pytest.fixture
+def mesh():
+    """A (pod=2, data=1, model=1) mesh over two of the host devices that
+    tests/conftest.py provides."""
+    if jax.device_count() < 2:
+        pytest.skip(f"needs 2 devices, found {jax.device_count()}")
+    return jax.make_mesh((2, 1, 1), ("pod", "data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 3,
+                         devices=jax.devices()[:2])
 
 
 def tiny_tree(seed=0):
@@ -44,13 +41,12 @@ class TestFedAvgSync:
         np.testing.assert_allclose(np.asarray(out["w"][0]),
                                    np.asarray(out["w"][1]), rtol=0)
 
-    def test_compressed_matches_plain_within_int8(self):
-        mesh = make_mesh()
+    def test_compressed_matches_plain_within_int8(self, mesh):
         stk = tiny_tree(1)
         glob = jax.tree.map(lambda p: p[0] * 0.9, stk)   # deltas ~0.1 scale
         w = jnp.asarray([1.0, 2.0])
         plain = mesh_fl.fedavg_sync(stk, w)
-        with compat.set_mesh(mesh):
+        with jax.set_mesh(mesh):
             comp = jax.jit(
                 lambda s, g, ww: mesh_fl.fedavg_sync_compressed(
                     s, g, ww, mesh, 2))(stk, glob, w)
@@ -61,8 +57,7 @@ class TestFedAvgSync:
             # int8 per-tensor quantization error bound on the delta
             assert err <= 2 * delta_amax / 127 + 1e-6, (k, err)
 
-    def test_round_step_sync_barrier(self):
-        mesh = make_mesh()
+    def test_round_step_sync_barrier(self, mesh):
         rules = R.make_rules("train")
         shard = R.ShardingCtx(mesh, rules)
         cfg = configs.get_config("phi3-mini-3.8b", smoke=True)
@@ -80,7 +75,7 @@ class TestFedAvgSync:
         step = mesh_fl.make_fl_round_step(cfg, opt=1e-2, shard=shard,
                                           local_steps=2, mesh=mesh,
                                           n_pods=2)
-        with compat.set_mesh(mesh):
+        with jax.set_mesh(mesh):
             new_stk, new_mu, losses = jax.jit(step)(stk, mu, batch, weights)
         assert losses.shape == (2,)
         assert bool(jnp.all(jnp.isfinite(losses)))

@@ -186,6 +186,23 @@ class TestRunAndReport:
         par = run_sweep(specs, parallel=True, processes=2)
         assert par == serial
 
+    def test_cells_start_no_jax_backend(self):
+        """The pool forks, so the sweep path must stay JAX-free: a cell
+        run in a fresh interpreter leaves no JAX backend initialized
+        (no device held, no runtime threads to fork)."""
+        import subprocess
+        code = (
+            "from repro.sweep import build_grid, run_cell\n"
+            "from jax._src import xla_bridge\n"
+            "spec = build_grid(policies=('fedcostaware',), "
+            "markets=('baseline',), seeds=range(1), n_clients=3, "
+            "n_epochs=2)[0]\n"
+            "run_cell(spec)\n"
+            "assert not xla_bridge.backends_are_initialized()\n")
+        env = dict(os.environ, PYTHONPATH=str(REPO / "src"))
+        subprocess.run([sys.executable, "-c", code], env=env, check=True,
+                       timeout=120)
+
 
 @pytest.mark.slow
 @pytest.mark.skipif(os.cpu_count() is None or os.cpu_count() < 4,

@@ -2,13 +2,6 @@
 behind the engine hook protocol, payload-exact egress billing, the
 quantized-update accuracy/egress trade, and step-time calibration
 against the measured-peak roofline."""
-import os
-
-# one host device per simulated client; must precede jax import (any
-# earlier test that initialized jax wins — the skipif below catches it)
-os.environ.setdefault("XLA_FLAGS",
-                      "--xla_force_host_platform_device_count=2")
-
 import jax
 import numpy as np
 import pytest
@@ -29,10 +22,13 @@ COMM_MARKET = MarketConfig(providers=(
                    spot_rate_sigma=0.0,
                    update_egress_usd_per_mb=0.001, uplink_mbps=100.0),))
 
-needs_devices = pytest.mark.skipif(
-    jax.device_count() < N_CLIENTS,
-    reason="needs >=2 devices (XLA_FLAGS set too late — another test "
-    "initialized jax first)")
+
+@pytest.fixture
+def needs_devices():
+    """One host device per client (tests/conftest.py provides four)."""
+    if jax.device_count() < N_CLIENTS:
+        pytest.skip(f"needs {N_CLIENTS} devices, found "
+                    f"{jax.device_count()}")
 
 
 def make_hooks(quantize=False, seed=0):
@@ -55,7 +51,7 @@ def run_real(hooks, rounds=2, quantize=False, seed=0):
 # The bridge end to end: real jitted steps inside the simulated loop.
 # ---------------------------------------------------------------------------
 @pytest.mark.slow
-@needs_devices
+@pytest.mark.usefixtures("needs_devices")
 class TestMeshTrainerBridge:
     def test_real_run_trains_and_bills_real_payload(self):
         hooks = make_hooks()
@@ -95,7 +91,7 @@ class TestMeshTrainerBridge:
 # Calibration: measured step time -> simulated epoch durations.
 # ---------------------------------------------------------------------------
 @pytest.mark.slow
-@needs_devices
+@pytest.mark.usefixtures("needs_devices")
 class TestCalibrationMeasured:
     def test_calibration_within_3x_of_roofline(self):
         hooks = make_hooks()
@@ -121,7 +117,7 @@ class TestCalibrationMeasured:
 class TestCalibrationMath:
     CAL = StepCalibration(measured_round_s=0.02, roofline_round_s=0.01,
                           flops=1e9, bytes_accessed=1e8,
-                          host_peak_flops=1e11, host_bw=1e10)
+                          peak_flops=1e11, peak_bw=1e10)
 
     def test_ratio_and_time_scale(self):
         assert self.CAL.ratio == pytest.approx(2.0)
