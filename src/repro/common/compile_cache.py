@@ -1,9 +1,9 @@
 """Where JAX keeps compiled programs between processes.
 
 A cold TPU process recompiles every program of the FL round, which can
-be most of a short run. JAX's persistent compilation cache avoids that,
-and its key includes the cache path, so the path must not move between
-runs.
+be most of a short run. JAX's persistent compilation cache avoids that
+when the next process looks in the same directory, so the path is fixed
+relative to the checkout.
 """
 from __future__ import annotations
 
