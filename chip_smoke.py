@@ -36,7 +36,6 @@ import dataclasses
 import gc
 import json
 import sys
-import time
 from pathlib import Path
 
 sys.path.insert(0, str(Path(__file__).resolve().parent / "src"))
@@ -61,39 +60,6 @@ def check(ok: bool, what: str) -> None:
 
 def log(msg: str) -> None:
     print(msg, flush=True)
-
-
-# ---------------------------------------------------------------------------
-# Compile accounting (JAX's own monitoring events).
-# ---------------------------------------------------------------------------
-class CompileClock:
-    """Inside its `with` block, sums JAX's backend-compile durations
-    (trace events nest, so they are left out) and counts
-    persistent-cache hits."""
-
-    def __init__(self):
-        self.seconds = 0.0
-        self.cache_hits = 0
-
-    def _on_duration(self, event, secs, **_):
-        if event == "/jax/core/compile/backend_compile_duration":
-            self.seconds += secs
-
-    def _on_event(self, event, **_):
-        if event == "/jax/compilation_cache/cache_hits":
-            self.cache_hits += 1
-
-    def __enter__(self):
-        import jax
-        jax.monitoring.register_event_duration_secs_listener(
-            self._on_duration)
-        jax.monitoring.register_event_listener(self._on_event)
-        return self
-
-    def __exit__(self, *exc):
-        import jax
-        jax.monitoring.unregister_event_duration_listener(self._on_duration)
-        jax.monitoring.unregister_event_listener(self._on_event)
 
 
 # ---------------------------------------------------------------------------
@@ -175,6 +141,7 @@ def round_phase(cfg, *, batch, seq, local_steps):
     rounds made to the largest leaf."""
     import jax
     import numpy as np
+    from repro.common import tracing
     from repro.common.config import CloudConfig, ClientProfile, FLRunConfig
     from repro.fl.runner import FLCloudRunner
     from repro.fl.training import calibrate
@@ -184,8 +151,8 @@ def round_phase(cfg, *, batch, seq, local_steps):
         seed=SEED, quantize_updates=True,
         clients=(ClientProfile("client_0", mean_epoch_s=600.0,
                                jitter=0.0),))
-    round_s = []
-    with CompileClock() as clock:
+    rec = tracing.start()
+    try:
         hooks = make_hooks(cfg, 1, batch=batch, seq=seq,
                            local_steps=local_steps, quantize=True,
                            use_pallas=True)
@@ -193,27 +160,24 @@ def round_phase(cfg, *, batch, seq, local_steps):
         big = int(np.argmax([x.size for x in leaves]))
         before = leaves[big]
         del leaves
-        aggregate = hooks.aggregate
-
-        def timed_aggregate(*args, **kw):
-            t0 = time.perf_counter()
-            aggregate(*args, **kw)
-            jax.block_until_ready((hooks.params_stk, hooks.mu_stk))
-            round_s.append(time.perf_counter() - t0)
-
-        hooks.aggregate = timed_aggregate
         res = FLCloudRunner(run_cfg,
                             cloud_cfg=CloudConfig(spot_rate_sigma=0.0),
                             hooks=hooks).run()
+    finally:
+        tracing.stop()
+    # a round ends when its losses reach the host
+    ends = [s.end_ns / 1e9 for s in rec.named("fl.aggregate")]
+    round_s = np.diff(ends)
     losses = [r["mean_loss"] for r in hooks.losses]
     dev = jax.devices()[0]
     log(f"round: rounds_completed {res.rounds_completed}, "
         f"total_cost ${res.total_cost:.4f}, comm_cost ${res.comm_cost:.6f}")
-    log(f"round: compile_s {clock.seconds:.1f} (backend compile; "
-        f"persistent-cache hits {clock.cache_hits})")
-    log(f"round: round_s {[round(t, 4) for t in round_s]}; steady "
-        f"round_s {float(np.median(round_s[1:])):.4f} (median of rounds "
-        f"2..{len(round_s)}, block_until_ready)")
+    log(f"round: compile_s {rec.counters.get('compile_s', 0):.1f} "
+        f"({rec.counters.get('compiles', 0)} compiles or cache loads; "
+        f"persistent-cache hits {rec.counters.get('cache_hits', 0)})")
+    log(f"round: round_s {[round(float(t), 4) for t in round_s]}; steady "
+        f"round_s {float(np.median(round_s)):.4f} (median of rounds "
+        f"2..{len(ends)}, end to end of the fl.aggregate spans)")
     log(f"round: per-round losses {losses}")
     check(res.rounds_completed == ROUNDS and len(losses) == ROUNDS,
           f"expected {ROUNDS} aggregated rounds, got {len(losses)}")

@@ -39,6 +39,7 @@ import numpy as np
 from repro.checkpoint.store import MemoryStore, ObjectStore
 from repro.cloud.accounting import CostAccountant
 from repro.cloud.simulator import CloudSimulator
+from repro.common import tracing
 from repro.common.config import (ClientProfile, CloudConfig, FLRunConfig,
                                  SchedulerConfig)
 from repro.core.events import (ClientLost, ClientReady,
@@ -228,11 +229,13 @@ class BaseEngine:
         hooks that accept it (legacy 2-argument overrides still work)."""
         if self.hooks is None:
             return
-        if self._aggregate_accepts_staleness:
-            self.hooks.aggregate(participants, round_idx,
-                                 staleness=staleness)
-        else:
-            self.hooks.aggregate(participants, round_idx)
+        tracing.count("rounds")
+        with tracing.span("fl.aggregate", round=round_idx):
+            if self._aggregate_accepts_staleness:
+                self.hooks.aggregate(participants, round_idx,
+                                     staleness=staleness)
+            else:
+                self.hooks.aggregate(participants, round_idx)
 
     def _publish_update_sent(self, c: str, round_idx: int) -> float:
         """Comms modeling: publish `ClientUpdateSent` for `c`'s finished

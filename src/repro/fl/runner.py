@@ -53,6 +53,7 @@ from repro.cloud.pricing import SpotMarket
 from repro.comms.channel import CommsModel, UplinkChannel
 from repro.comms.payload import UpdatePayload
 from repro.cloud.simulator import CloudSimulator
+from repro.common import tracing
 from repro.common.config import CloudConfig, FLRunConfig, SchedulerConfig
 from repro.core.events import EventBus, RunCompleted
 from repro.core.eventlog import EventRecorder
@@ -317,37 +318,38 @@ class FLCloudRunner:
         """Execute the run to completion: start the engine, drain the
         simulator, publish the terminal `RunCompleted` summary, persist
         the event log if requested, and return the `RunResult`."""
-        if self._fleet is not None:
-            res = self._fleet.run()
-            # fleet-mode terminal summary: per-client costs live in
-            # RunResult.per_client_cost and, per step, in
-            # FleetStepSummary.client_cost_delta (schema v6) — the
-            # terminal event stays aggregate, so client_costs is
-            # deliberately empty
+        with tracing.span("fl.run"):
+            if self._fleet is not None:
+                res = self._fleet.run()
+                # fleet-mode terminal summary: per-client costs live in
+                # RunResult.per_client_cost and, per step, in
+                # FleetStepSummary.client_cost_delta (schema v6) — the
+                # terminal event stays aggregate, so client_costs is
+                # deliberately empty
+                self.bus.publish(RunCompleted(
+                    res.makespan_s, makespan_s=res.makespan_s,
+                    total_cost=res.total_cost, client_costs={},
+                    rounds_completed=res.rounds_completed,
+                    excluded_clients=tuple(res.excluded_clients),
+                    final_round_idx=res.rounds_completed - 1))
+                if self.record_to is not None:
+                    self.recorder.dump(self.record_to)
+                return res
+            self.engine.start()
+            self.sim.run_until_idle()
+            self.timeline.close(self.sim.now)   # no-op on complete runs
+            res = self.engine.result()
+            # terminal summary, published after the drain: the sync engine's
+            # makespan includes post-finish drain time, so only here is the
+            # true makespan known. Costs are frozen once the engine finishes,
+            # making this snapshot == the accountant's state at finish.
             self.bus.publish(RunCompleted(
-                res.makespan_s, makespan_s=res.makespan_s,
-                total_cost=res.total_cost, client_costs={},
+                self.sim.now, makespan_s=res.makespan_s,
+                total_cost=res.total_cost,
+                client_costs=dict(res.per_client_cost),
                 rounds_completed=res.rounds_completed,
                 excluded_clients=tuple(res.excluded_clients),
                 final_round_idx=res.rounds_completed - 1))
             if self.record_to is not None:
                 self.recorder.dump(self.record_to)
             return res
-        self.engine.start()
-        self.sim.run_until_idle()
-        self.timeline.close(self.sim.now)   # no-op on complete runs
-        res = self.engine.result()
-        # terminal summary, published after the drain: the sync engine's
-        # makespan includes post-finish drain time, so only here is the
-        # true makespan known. Costs are frozen once the engine finishes,
-        # making this snapshot == the accountant's state at finish.
-        self.bus.publish(RunCompleted(
-            self.sim.now, makespan_s=res.makespan_s,
-            total_cost=res.total_cost,
-            client_costs=dict(res.per_client_cost),
-            rounds_completed=res.rounds_completed,
-            excluded_clients=tuple(res.excluded_clients),
-            final_round_idx=res.rounds_completed - 1))
-        if self.record_to is not None:
-            self.recorder.dump(self.record_to)
-        return res

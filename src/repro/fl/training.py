@@ -48,6 +48,7 @@ from jax import lax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro import configs
+from repro.common import tracing
 from repro.common.config import ClientProfile, ModelConfig
 from repro.comms.payload import UpdatePayload
 from repro.data.synthetic import token_stream
@@ -57,6 +58,13 @@ from repro.kernels.grad_quant import ops as gq
 from repro.models import lm
 
 _POD = P("pod")
+
+# `jax.named_scope` names of the round programs' parts, as a compiled
+# op's `op_name` carries them: the local program's forward ops read
+# `jvp(forward)`, its backward ops (remat recompute included)
+# `transpose(jvp(forward))`; FedAvg's read `delta`, `codec` or `sum`.
+FORWARD, OPTIMIZER = "forward", "optimizer"
+DELTA, CODEC, SUM = "delta", "codec", "sum"
 
 
 def _client_mesh(n_clients: int) -> jax.sharding.Mesh:
@@ -89,15 +97,19 @@ def make_round_programs(cfg: ModelConfig, mesh: jax.sharding.Mesh, *,
     stk = NamedSharding(mesh, _POD)
 
     def local_train(params, mu, client_batches):
+        def forward(pp, batch):
+            with jax.named_scope(FORWARD):
+                return lm.loss_fn(pp, cfg, batch)
+
         def step(carry, batch):
             p, m = carry
-            loss, g = jax.value_and_grad(
-                lambda pp: lm.loss_fn(pp, cfg, batch))(p)
-            m = jax.tree.map(
-                lambda mi, gi: 0.9 * mi + gi.astype(jnp.float32), m, g)
-            p = jax.tree.map(
-                lambda pi, mi: (pi.astype(jnp.float32)
-                                - lr * mi).astype(pi.dtype), p, m)
+            loss, g = jax.value_and_grad(forward)(p, batch)
+            with jax.named_scope(OPTIMIZER):
+                m = jax.tree.map(
+                    lambda mi, gi: 0.9 * mi + gi.astype(jnp.float32), m, g)
+                p = jax.tree.map(
+                    lambda pi, mi: (pi.astype(jnp.float32)
+                                    - lr * mi).astype(pi.dtype), p, m)
             return (p, m), loss
 
         (params, mu), losses = lax.scan(step, (params, mu),
@@ -115,12 +127,15 @@ def make_round_programs(cfg: ModelConfig, mesh: jax.sharding.Mesh, *,
         bcast = lambda x, ref: x.reshape((-1,) + (1,) * (ref.ndim - 1))
 
         def leaf(n, o):
-            d = n.astype(jnp.float32) - o.astype(jnp.float32)
+            with jax.named_scope(DELTA):
+                d = n.astype(jnp.float32) - o.astype(jnp.float32)
             if quantize:
-                d = jax.vmap(codec)(d)
+                with jax.named_scope(CODEC):
+                    d = jax.vmap(codec)(d)
             # elementwise weighting keeps the sum in fp32 (a dot over
             # the client dim would run at the TPU's bf16 default)
-            avg = lax.psum(jnp.sum(bcast(wn, d) * d, axis=0), "pod")
+            with jax.named_scope(SUM):
+                avg = lax.psum(jnp.sum(bcast(wn, d) * d, axis=0), "pod")
             return (o.astype(jnp.float32) + avg).astype(o.dtype)
 
         keep = w > 0
@@ -163,25 +178,29 @@ class MeshTrainerHooks(TrainerHooks):
         n = len(self.clients)
         self.mesh = _client_mesh(n)
         self.stacked = NamedSharding(self.mesh, _POD)
-        self._local_fn, self._avg_fn = make_round_programs(
-            self.cfg, self.mesh, lr=lr, quantize=quantize,
-            use_pallas=use_pallas)
+        with tracing.span("fl.hooks_init"):
+            with tracing.span("fl.build_programs"):
+                self._local_fn, self._avg_fn = make_round_programs(
+                    self.cfg, self.mesh, lr=lr, quantize=quantize,
+                    use_pallas=use_pallas)
 
-        def init(key):
-            p = lm.init_params(self.cfg, key)
-            stk = jax.tree.map(
-                lambda x: jnp.broadcast_to(x[None], (n,) + x.shape), p)
-            return stk, jax.tree.map(
-                lambda x: jnp.zeros(x.shape, jnp.float32), stk)
+            def init(key):
+                p = lm.init_params(self.cfg, key)
+                stk = jax.tree.map(
+                    lambda x: jnp.broadcast_to(x[None], (n,) + x.shape), p)
+                return stk, jax.tree.map(
+                    lambda x: jnp.zeros(x.shape, jnp.float32), stk)
 
-        self.params_stk, self.mu_stk = jax.jit(
-            init, out_shardings=(self.stacked, self.stacked))(
-                jax.random.PRNGKey(seed))
+            with tracing.span("fl.init_params"):
+                self.params_stk, self.mu_stk = jax.jit(
+                    init, out_shardings=(self.stacked, self.stacked))(
+                        jax.random.PRNGKey(seed))
+            with tracing.span("fl.streams"):
+                self._streams = [
+                    token_stream(self.cfg.vocab_size, batch, seq,
+                                 seed=seed + 17 * i) for i in range(n)]
         self._base_w = np.array(
             [float((weights or {}).get(c, 1.0)) for c in self.clients])
-        self._streams = [token_stream(self.cfg.vocab_size, batch, seq,
-                                      seed=seed + 17 * i)
-                         for i in range(n)]
         self._participants: Dict[str, int] = {}   # client -> last round
         self.losses: List[dict] = []              # per-aggregation record
 
@@ -210,9 +229,14 @@ class MeshTrainerHooks(TrainerHooks):
             w[self.slot[c]] = (
                 self._base_w[self.slot[c]]
                 * JaxTrainerHooks.staleness_discount(stale.get(c, 0)))
-        new_p, new_mu, losses = self.local_round(self.next_batches())
-        self.params_stk, self.mu_stk = self.fedavg(new_p, new_mu, w)
-        losses = np.asarray(losses)
+        with tracing.span("fl.next_batches"):
+            batches = self.next_batches()
+        with tracing.span("fl.local_dispatch"):
+            new_p, new_mu, losses = self.local_round(batches)
+        with tracing.span("fl.fedavg_dispatch"):
+            self.params_stk, self.mu_stk = self.fedavg(new_p, new_mu, w)
+        with tracing.span("fl.loss_fetch"):     # waits on the device
+            losses = np.asarray(losses)
         self.losses.append({
             "round": round_idx,
             "mean_loss": float(np.mean(

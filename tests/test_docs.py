@@ -58,6 +58,7 @@ PUBLIC_MODULES = [
     "src/repro/forecast/strategy.py",
     "src/repro/checkpoint/store.py",
     "src/repro/checkpoint/snapshots.py",
+    "src/repro/common/tracing.py",
 ]
 DOC_COVERAGE_FLOOR = 0.9
 
