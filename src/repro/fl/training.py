@@ -38,6 +38,7 @@ peaks measured on the host for CPU devices — and rewrites
 from __future__ import annotations
 
 import dataclasses
+import functools
 import time
 from typing import Dict, List, Optional, Sequence
 
@@ -116,11 +117,6 @@ def make_round_programs(cfg: ModelConfig, mesh: jax.sharding.Mesh, *,
                                         client_batches)
         return params, mu, losses
 
-    def codec(d):
-        q, s = gq.quantize(d, use_pallas=use_pallas)
-        return gq.dequantize(q, s, d.shape, jnp.float32,
-                             use_pallas=use_pallas)
-
     def fedavg(new_p, old_p, new_mu, old_mu, w):
         # per device: slot-local arrays with a leading client dim
         wn = w / jnp.maximum(lax.psum(jnp.sum(w), "pod"), 1e-12)
@@ -129,19 +125,40 @@ def make_round_programs(cfg: ModelConfig, mesh: jax.sharding.Mesh, *,
         def leaf(n, o):
             with jax.named_scope(DELTA):
                 d = n.astype(jnp.float32) - o.astype(jnp.float32)
-            if quantize:
-                with jax.named_scope(CODEC):
-                    d = jax.vmap(codec)(d)
             # elementwise weighting keeps the sum in fp32 (a dot over
             # the client dim would run at the TPU's bf16 default)
             with jax.named_scope(SUM):
                 avg = lax.psum(jnp.sum(bcast(wn, d) * d, axis=0), "pod")
             return (o.astype(jnp.float32) + avg).astype(o.dtype)
 
+        def codec_leaf(n, o):
+            # the same sum over the int8 codec's deltas, in three passes:
+            # the leaf's row views (a free reshape where the view keeps
+            # the leaf's rows), the two kernels (quantize forms the fp32
+            # delta in VMEM), then the weighted sum and add-back on the
+            # views. The barrier holds the views as built, so that the
+            # compiler neither redoes a relayout for the add-back nor
+            # moves the add-back off the views.
+            with jax.named_scope(DELTA):
+                nv, ov = lax.optimization_barrier(
+                    (jax.vmap(gq.rows)(n), jax.vmap(gq.rows)(o)))
+            with jax.named_scope(CODEC):
+                q, s = jax.vmap(functools.partial(
+                    gq.quantize_delta, use_pallas=use_pallas))(nv, ov)
+                d = jax.vmap(functools.partial(
+                    gq.dequantize, shape=nv.shape[1:],
+                    use_pallas=use_pallas))(q, s)
+            with jax.named_scope(SUM):
+                avg = lax.psum(jnp.sum(bcast(wn, d) * d, axis=0), "pod")
+                out = (ov.astype(jnp.float32) + avg).astype(o.dtype)
+                return jax.vmap(functools.partial(
+                    gq.unrows, shape=o.shape[1:]))(out)
+
         keep = w > 0
         mu = jax.tree.map(lambda n, o: jnp.where(bcast(keep, n), n, o),
                           new_mu, old_mu)
-        return jax.tree.map(leaf, new_p, old_p), mu
+        return jax.tree.map(codec_leaf if quantize else leaf,
+                            new_p, old_p), mu
 
     # check_vma=False: the Pallas kernels' out_shapes carry no
     # varying-mesh-axis annotation, which the check would demand

@@ -9,6 +9,7 @@ worker collects the same tests and only the worker running this file
 loads the TPU library.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -97,14 +98,14 @@ def test_grad_quant_phi3_leaves(one_chip, shape):
     assert text.count("tpu_custom_call") >= 2
 
 
-def test_phi3_one_chip_round_fits(topo):
-    """Both round programs of the one-chip cut compile, run the Pallas
-    kernels, and leave the HBM share the cut was sized for."""
+@pytest.fixture(scope="module")
+def phi3_round(topo):
+    """Both round programs of the one-chip phi3 cut (int8 FedAvg),
+    compiled for the described v5e, and the cut's parameter count."""
     from repro.configs.phi3_mini_3p8b import (ONE_CHIP, ONE_CHIP_BATCH,
                                               ONE_CHIP_LOCAL_STEPS,
                                               ONE_CHIP_SEQ)
     from repro.fl.training import make_round_programs
-    from repro.launch.roofline import V5E
     from repro.models import lm
     mesh = jax.sharding.Mesh(np.array(topo.devices[:1]), ("pod",),
                              axis_types=(jax.sharding.AxisType.Auto,))
@@ -118,10 +119,32 @@ def test_phi3_one_chip_round_fits(topo):
                                ONE_CHIP_SEQ), jnp.int32)
                for k in ("tokens", "labels")}
     w = _shape(stk, (1,), jnp.float32)
-    for compiled in (local.lower(params, mu, batches).compile(),
-                     fedavg.lower(params, params, mu, mu, w).compile()):
+    return {"local": local.lower(params, mu, batches).compile(),
+            "fedavg": fedavg.lower(params, params, mu, mu, w).compile(),
+            "leaves": len(jax.tree.leaves(params))}
+
+
+def test_phi3_one_chip_round_fits(phi3_round):
+    """Both round programs of the one-chip cut compile, run the Pallas
+    kernels, and leave the HBM share the cut was sized for."""
+    from repro.launch.roofline import V5E
+    for compiled in (phi3_round["local"], phi3_round["fedavg"]):
         assert "tpu_custom_call" in compiled.as_text()
         m = compiled.memory_analysis()
         used = (m.argument_size_in_bytes + m.output_size_in_bytes
                 + m.temp_size_in_bytes - m.alias_size_in_bytes)
         assert used <= V5E.hbm_bytes - MIN_FREE_BYTES, used
+
+
+def test_phi3_fedavg_keeps_the_benchmark_hooks(phi3_round):
+    """The FedAvg program is the module `jit_fedavg`, and each parameter
+    leaf has one Pallas call named after a `quantize` wrapper and one
+    after a `dequantize` wrapper, under vmap: the names by which the
+    benchmark finds FedAvg's device time and the codec's kernels."""
+    text = phi3_round["fedavg"].as_text()
+    assert text.startswith("HloModule jit_fedavg")
+    calls = re.findall(r"\n\s*(?:ROOT )?%?([\w.\-]+) = [^\n]*"
+                       r'custom_call_target="tpu_custom_call"', text)
+    for prefix in ("vmap_jit_quantize", "vmap_jit_dequantize"):
+        assert sum(c.startswith(prefix) for c in calls) == \
+            phi3_round["leaves"], (prefix, calls)

@@ -11,7 +11,7 @@ from repro.kernels.flash_attention.ref import reference_attention
 from repro.kernels.ssd.ops import ssd
 from repro.kernels.ssd.ref import ssd_reference
 from repro.kernels.grad_quant.ops import quantize, dequantize
-from repro.kernels.grad_quant import kernel as QK, ref as QR
+from repro.kernels.grad_quant import kernel as QK, ops as GQ, ref as QR
 
 
 def _fold(x):
@@ -179,6 +179,52 @@ class TestGradQuant:
         q, s = quantize(x, use_pallas=True, interpret=True)
         xd = dequantize(q, s, (2, 100), use_pallas=True, interpret=True)
         assert float(jnp.max(jnp.abs(xd))) == 0.0
+
+    @pytest.mark.parametrize("shape, view", [
+        ((5, 3072, 8192), (5, 3072, 8192)),      # the leaf's own rows
+        ((5, 32, 96, 3072), (5, 32, 96, 3072)),
+        ((5, 3072, 32, 96), (5, 3072, 3072)),    # the trailing dims merged
+        ((6, 1024), (6, 1024)),                  # a block spans a row pair
+        ((3072, 32064), (48096, 2048)),          # rows wider than MAX_WIDTH
+        ((37, 3072), (56, 2048)),                # a partial last block
+        ((5, 3072), (8, 2048)),
+        ((4, 5, 1024), (10, 2048)),              # a block would span matrices
+    ])
+    def test_row_view(self, shape, view):
+        assert GQ.row_view(shape) == view
+
+    @pytest.mark.parametrize("use_pallas", [True, False],
+                             ids=["pallas", "jnp"])
+    @pytest.mark.parametrize("shape, dtype", [
+        ((3072, 8192), jnp.bfloat16),
+        ((5, 3072, 32, 96), jnp.bfloat16),
+        ((37, 3072), jnp.bfloat16),
+        ((3072,), jnp.float32),
+    ])
+    def test_delta_codec_bit_identical(self, shape, dtype, use_pallas):
+        """The codec on a leaf's row views of new and old gives, bit for
+        bit, the codes, scales and dequantized delta of `quantize` on
+        the fp32 delta. The first block is all zero: the 1e-12 floor."""
+        rng = np.random.RandomState(len(shape))
+        old = jnp.asarray(rng.randn(*shape) * 0.02, dtype)
+        new = (old.astype(jnp.float32)
+               + jnp.asarray(rng.randn(*shape) * 1e-3, jnp.float32))
+        new = new.astype(dtype).reshape(-1).at[:GQ.BLOCK].set(
+            old.reshape(-1)[:GQ.BLOCK]).reshape(shape)
+        d = new.astype(jnp.float32) - old.astype(jnp.float32)
+        q_want, s_want = quantize(d)
+        y_want = dequantize(q_want, s_want, shape)
+
+        kw = dict(use_pallas=use_pallas, interpret=use_pallas)
+        q, s = GQ.quantize_delta(GQ.rows(new), GQ.rows(old), **kw)
+        y = dequantize(q, s, shape, **kw)
+        q_got, s_got = GQ.blocks(q, s)
+        bits = lambda a: np.ascontiguousarray(np.asarray(a)).view(np.uint8)
+        np.testing.assert_array_equal(bits(q_got), bits(q_want))
+        np.testing.assert_array_equal(bits(s_got), bits(s_want))
+        np.testing.assert_array_equal(bits(y), bits(y_want))
+        assert float(s_got[0, 0]) == float(np.float32(1e-12) / 127)
+        assert not np.any(np.asarray(y).reshape(-1)[:GQ.BLOCK])
 
 
 class TestFlashAttentionGrad:

@@ -2,16 +2,26 @@
 behind the engine hook protocol, payload-exact egress billing, the
 quantized-update accuracy/egress trade, and step-time calibration
 against the measured-peak roofline."""
+import contextlib
+import dataclasses
+
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.experimental.pallas import tpu as pltpu
+from jax.sharding import NamedSharding, PartitionSpec as P
 
+from repro import configs
 from repro.common.config import (CloudConfig, ClientProfile, FLRunConfig,
                                  MarketConfig, ProviderConfig)
 from repro.comms.payload import UpdatePayload
 from repro.fl.runner import FLCloudRunner
 from repro.fl.training import (MeshTrainerHooks, StepCalibration,
-                               calibrate, calibrated_profiles)
+                               calibrate, calibrated_profiles,
+                               make_round_programs)
+from repro.kernels.grad_quant import ops as gq
+from repro.models import lm
 
 N_CLIENTS = 2
 NAMES = tuple(f"client_{i}" for i in range(N_CLIENTS))
@@ -85,6 +95,67 @@ class TestMeshTrainerBridge:
         # the --assert-comm-win benchmark gate enforces too
         delta = abs(q_hooks.final_loss() - fp_hooks.final_loss())
         assert delta <= 0.75
+
+
+# ---------------------------------------------------------------------------
+# The FedAvg program's int8 leaf path against the codec, leaf by leaf.
+# ---------------------------------------------------------------------------
+@pytest.mark.parametrize("use_pallas", [False, True], ids=["jnp", "pallas"])
+@pytest.mark.parametrize("weights", [[0.6], [0.6, 0.0], [0.25, 0.75]],
+                         ids=["1dev", "2dev-weight0", "2dev"])
+def test_fedavg_int8_leaf_path(weights, use_pallas):
+    """`fedavg` under `quantize=True` gives, bit for bit, old + the sum
+    over slots of wn * dequantize(quantize(new - old)) in fp32, each
+    leaf's delta through the codec whole as before, stored in the leaf's
+    dtype; a weight-0 slot keeps its old momentum."""
+    n = len(weights)
+    if jax.device_count() < n:
+        pytest.skip(f"needs {n} devices, found {jax.device_count()}")
+    cfg = dataclasses.replace(configs.get_config("phi3-mini-3.8b",
+                                                 smoke=True),
+                              param_dtype="bfloat16")
+    mesh = jax.make_mesh((n,), ("pod",),
+                         axis_types=(jax.sharding.AxisType.Auto,),
+                         devices=jax.devices()[:n])
+    _, fedavg = make_round_programs(cfg, mesh, lr=5e-3, quantize=True,
+                                    use_pallas=use_pallas)
+    leaves, tree = jax.tree.flatten(lm.init_params(cfg,
+                                                   jax.random.PRNGKey(3)))
+    normal = lambda seed, i, x: jax.random.normal(
+        jax.random.fold_in(jax.random.PRNGKey(seed), i), (n,) + x.shape)
+    old = [jnp.broadcast_to(x[None], (n,) + x.shape) for x in leaves]
+    new = [(o.astype(jnp.float32) + 1e-2 * normal(4, i, x)).astype(x.dtype)
+           for i, (o, x) in enumerate(zip(old, leaves))]
+    old_mu = [normal(5, i, x) for i, x in enumerate(leaves)]
+    new_mu = [normal(6, i, x) for i, x in enumerate(leaves)]
+    w = np.asarray(weights, np.float32)
+    wn = w / np.maximum(np.sum(w, dtype=np.float32), np.float32(1e-12))
+
+    want = []
+    for nl, ol in zip(new, old):
+        avg = None
+        for c in range(n):
+            d = nl[c].astype(jnp.float32) - ol[c].astype(jnp.float32)
+            term = wn[c] * gq.dequantize(*gq.quantize(d), d.shape)
+            avg = term if avg is None else avg + term
+        want.append((ol[0].astype(jnp.float32) + avg).astype(ol.dtype))
+    mu_want = [np.where(w.reshape((-1,) + (1,) * (m.ndim - 1)) > 0,
+                        np.asarray(m), np.asarray(o))
+               for m, o in zip(new_mu, old_mu)]
+
+    stk = NamedSharding(mesh, P("pod"))
+    put = lambda xs: jax.device_put(tree.unflatten(xs), stk)
+    interpret = (pltpu.force_tpu_interpret_mode() if use_pallas
+                 else contextlib.nullcontext())
+    with interpret:
+        got, mu = fedavg(put(new), put(old), put(new_mu), put(old_mu),
+                         jax.device_put(w, stk))
+    bits = lambda a: np.ascontiguousarray(np.asarray(a)).view(np.uint8)
+    for g, want_leaf in zip(jax.tree.leaves(got), want):
+        for c in range(n):
+            np.testing.assert_array_equal(bits(g[c]), bits(want_leaf))
+    for m, m_want in zip(jax.tree.leaves(mu), mu_want):
+        np.testing.assert_array_equal(bits(m), bits(m_want))
 
 
 # ---------------------------------------------------------------------------
