@@ -5,7 +5,8 @@ Everything that belongs to one of them, or to one per-layer metric, is
 a file of its own under bench/, found from the name alone:
 
   bench/configs/<config>.json   sizes as run, source, cut, reference
-  bench/reference/<ref>.py      the configuration's plain reference
+  bench/reference/<ref>.py      the configuration's plain reference:
+                                param_specs, loss, step_flops
   bench/traffic/<traffic>.json  clients, steps, batch, sequence, codec
   bench/limits/<cell>.json      each compared number's limit, readings
   bench/metrics/<metric>.py     per-layer reader: read(record) -> value

@@ -18,12 +18,21 @@ whole HLO instruction; the record keeps the instruction's name
 after the jitted wrapper that called it). `host` holds the benchmark's
 own spans (TraceAnnotation events named "bench.<span>"). Everything below works
 on that record, so a recorded excerpt can test it (bench/tests).
+
+A TPU op event carries no `op_name`. `op_names` reads each
+instruction's `op_name` from the compiled HLO text of a program, and
+`program_ops` picks a program's ops by the "XLA Modules" events that
+enclose them, since instruction names repeat across programs. A scope
+is a component of an `op_name` path ("jit(local_train)/vmap()/while/
+body/closed_call/transpose(jvp(forward))/dot_general"); `in_scope`
+tests for one once `jvp(...)` and `transpose(...)` are unwrapped.
 """
 from __future__ import annotations
 
+import bisect
 import glob
 import re
-from typing import Dict, Iterable, List, Sequence, Tuple
+from typing import Dict, Iterable, List, Optional, Sequence, Tuple
 
 Interval = Tuple[float, float]
 
@@ -46,6 +55,69 @@ def _op(text: str) -> Tuple[str, str, str]:
     target = _TARGET.search(rest)
     return (name.lstrip("%"), opcode.group(1) if opcode else "",
             target.group(1) if target else "")
+
+
+_MODULE = re.compile(r"^HloModule ([^\s,]+)", re.M)
+_INSTRUCTION = re.compile(r"^\s*(?:ROOT )?%([^\s=]+) = (.*)$", re.M)
+_OP_NAME = re.compile(r'metadata=\{op_name="((?:[^"\\]|\\.)*)"')
+_WRAPPED = re.compile(r"(jvp|transpose)\((.*)\)")
+
+
+def op_names(hlo_text: str) -> Tuple[str, Dict[str, str]]:
+    """(module name, {instruction name: op_name}) of a compiled HLO
+    module's text: every instruction of it, "" for one without an
+    `op_name`, so that an op the text does not hold can be told apart."""
+    module = _MODULE.search(hlo_text)
+    if not module:
+        raise ValueError("no HloModule line in the HLO text")
+    names = {}
+    for name, rest in _INSTRUCTION.findall(hlo_text):
+        op = _OP_NAME.search(rest)
+        names[name] = op.group(1) if op else ""
+    return module.group(1), names
+
+
+def _components(op_name: str) -> List[str]:
+    """The path components of an op_name: split at "/" outside
+    parentheses."""
+    out, depth, start = [], 0, 0
+    for i, ch in enumerate(op_name):
+        if ch == "(":
+            depth += 1
+        elif ch == ")":
+            depth -= 1
+        elif ch == "/" and depth == 0:
+            out.append(op_name[start:i])
+            start = i + 1
+    out.append(op_name[start:])
+    return out
+
+
+def in_scope(op_name: str, scope: str, pass_: Optional[str] = None
+             ) -> bool:
+    """Whether `scope` is a component of `op_name` once `jvp(...)` and
+    `transpose(...)` are unwrapped. `pass_` "backward" asks for it under
+    a `transpose` (the backward pass, remat recompute included),
+    "forward" for it under a `jvp` and no `transpose`."""
+    wraps = set()
+    found = False
+    for comp in _components(op_name):
+        seen = []
+        m = _WRAPPED.fullmatch(comp)
+        while m:
+            seen.append(m.group(1))
+            comp = m.group(2)
+            m = _WRAPPED.fullmatch(comp)
+        if comp == scope:
+            found = True
+            wraps.update(seen)
+    if not found or pass_ is None:
+        return found
+    if pass_ == "backward":
+        return "transpose" in wraps
+    if pass_ == "forward":
+        return "jvp" in wraps and "transpose" not in wraps
+    raise ValueError(f"pass_ is 'forward', 'backward' or None, not {pass_!r}")
 
 
 def load_xplane(trace_dir: str) -> Dict:
@@ -144,6 +216,20 @@ def module_events(dev: Dict, prefix: str, lo: float, hi: float
     inside [lo, hi]."""
     return [(s, s + d) for n, s, d in dev["modules"]
             if n.startswith(prefix) and s >= lo and s + d <= hi]
+
+
+def program_ops(dev: Dict, runs: Sequence[Interval]
+                ) -> Iterable[Tuple[str, float]]:
+    """(instruction name, ns) of each op that starts inside one of
+    `runs` (a program's module events, as `module_events` gives them),
+    clipped to its run. Control flow (`while`, `conditional`, `call`) is
+    left out: its time is that of the ops in its body."""
+    runs = sorted(runs)
+    starts = [a for a, _ in runs]
+    for name, opcode, _, s, d in dev["ops"]:
+        i = bisect.bisect_right(starts, s) - 1
+        if i >= 0 and s < runs[i][1] and opcode not in CONTROL_FLOW:
+            yield name, min(s + d, runs[i][1]) - s
 
 
 def kernel_ops(dev: Dict, prefixes: Sequence[str], lo: float, hi: float

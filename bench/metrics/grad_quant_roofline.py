@@ -16,9 +16,8 @@ KERNELS = ("vmap_jit_quantize", "vmap_jit_dequantize")
 
 def read(r):
     import jax
-    from harness.cell import reference_module
     from reference.common import is_spec
-    specs = reference_module(r.cell).param_specs(r.model)
+    specs = r.reference.param_specs(r.model)
     sizes = [math.prod(s.shape) for s in jax.tree.leaves(specs,
                                                          is_leaf=is_spec)]
     per_round = sum(codec_bytes(n) for n in sizes) / r.peaks.hbm_bw

@@ -1,0 +1,10 @@
+"""local_fwd_ms: device milliseconds per run of the local-training
+program (HLO module `jit_local_train`) in its forward pass: the ops whose
+op_name holds the program's `forward` scope under `jvp` and no
+`transpose`, averaged over the runs in the traced window and over the
+chips. Source: the device trace, with each op's op_name read from the
+program's compiled HLO text (run.py `Record.scope_ms`)."""
+
+
+def read(r):
+    return r.scope_ms("jit_local_train", "forward", "forward")

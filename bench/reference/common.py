@@ -86,6 +86,21 @@ def make_params(specs, lo, hi, copies: int = 0):
     return jax.tree.unflatten(treedef, vals)
 
 
+def spec_size(specs) -> int:
+    """Elements of every parameter in `specs`."""
+    return sum(math.prod(s.shape)
+               for s in jax.tree.leaves(specs, is_leaf=is_spec))
+
+
+def matmul_params(specs, tie_embeddings: bool) -> int:
+    """Parameters that multiply activations, from the specs: all but
+    the embedding table where the head is separate (its lookup is free);
+    all of them where the head is the tied table. Norm scales count, as
+    6 N counts them."""
+    lookup = 0 if tie_embeddings else spec_size(specs["embed"])
+    return spec_size(specs) - lookup
+
+
 def abstract(specs, copies: int = 0):
     return jax.tree.map(
         lambda s: jax.ShapeDtypeStruct(

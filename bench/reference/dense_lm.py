@@ -15,7 +15,8 @@ import math
 import jax
 import jax.numpy as jnp
 
-from reference.common import Spec, cross_entropy, rms_norm
+from harness.flops import causal_attention_fwd_flops, train_step_flops
+from reference.common import Spec, cross_entropy, matmul_params, rms_norm
 
 
 def param_specs(model):
@@ -45,6 +46,18 @@ def param_specs(model):
         specs["lm_head"] = {"table": Spec((d, v), pd, "normal",
                                           1.0 / math.sqrt(d))}
     return specs
+
+
+def step_flops(model, batch: int, seq: int) -> float:
+    """Model FLOPs of one training step (harness/flops.py's rules): 6 N
+    per token over the matmul parameters of `param_specs`, plus three
+    times every layer's causal attention forward."""
+    nq = model["num_heads"]
+    h = model.get("head_dim") or model["d_model"] // nq
+    mix = model["num_layers"] * causal_attention_fwd_flops(batch, seq, nq, h)
+    return train_step_flops(
+        matmul_params(param_specs(model), model["tie_embeddings"]),
+        batch * seq, mix)
 
 
 def _rope(x, theta):

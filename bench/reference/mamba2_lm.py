@@ -22,7 +22,8 @@ import math
 import jax
 import jax.numpy as jnp
 
-from reference.common import Spec, cross_entropy, rms_norm
+from harness.flops import ssd_fwd_flops, train_step_flops
+from reference.common import Spec, cross_entropy, matmul_params, rms_norm
 
 HEAD_BLOCK = 8      # heads per block of the quadratic form
 
@@ -67,6 +68,19 @@ def param_specs(model):
         specs["lm_head"] = {"table": Spec((d, v), pd, "normal",
                                           1.0 / math.sqrt(d))}
     return specs
+
+
+def step_flops(model, batch: int, seq: int) -> float:
+    """Model FLOPs of one training step (harness/flops.py's rules): 6 N
+    per token over the matmul parameters of `param_specs`, plus three
+    times every layer's SSD chunked scan forward."""
+    s, _, nh, _, _ = _dims(model)
+    mix = model["num_layers"] * ssd_fwd_flops(
+        batch, seq, chunk=s["chunk_size"], d_state=s["d_state"],
+        n_groups=s["n_groups"], num_heads=nh, head_dim=s["head_dim"])
+    return train_step_flops(
+        matmul_params(param_specs(model), model["tie_embeddings"]),
+        batch * seq, mix)
 
 
 def _conv(u, w, b):

@@ -23,7 +23,8 @@ Runs one cell of BENCHMARK.json once, on the machine it is started on:
    the 90th percentile of the rounds' wall times (aggregate end to
    aggregate end). With `--trace 1` the window lasts at most
    TRACE_SECONDS under the profiler and the per-layer metrics are read
-   from the trace and the benchmark's host spans.
+   from the trace, the benchmark's host spans and the round programs'
+   compiled HLO text (each op's op_name, for device time by scope).
 4. `correct`: with the program's state freed, the plain fp32 reference
    (bench/reference) runs the same three rounds from the same weights
    and rows, and the numbers of harness/check.py are held to the cell's
@@ -39,6 +40,7 @@ import time
 T_PROCESS = time.perf_counter()
 
 import argparse  # noqa: E402
+import dataclasses  # noqa: E402
 import functools  # noqa: E402
 import gc  # noqa: E402
 import json  # noqa: E402
@@ -46,6 +48,7 @@ import os  # noqa: E402
 import sys  # noqa: E402
 import tempfile  # noqa: E402
 import traceback  # noqa: E402
+import typing  # noqa: E402
 from pathlib import Path  # noqa: E402
 
 BENCH = Path(__file__).resolve().parent
@@ -73,9 +76,14 @@ def configure_compile_cache() -> str:
     """Every program in the persistent cache: at the fixed
     <checkout>/.jax_cache unless JAX_COMPILATION_CACHE_DIR says where.
     The TPU runtime writes no log files (by default it would, under a
-    fixed path in /tmp). Call before JAX starts its backend."""
+    fixed path in /tmp). Programs are keyed by their metadata too:
+    otherwise an executable loaded from the cache keeps the `op_name`s
+    of the program first compiled under its key, which the scopes are
+    read from. Traced and untraced runs share the key, so either finds
+    what the other compiled. Call before JAX starts its backend."""
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
     import jax
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
     path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if not path:
         path = str(ROOT / ".jax_cache")
@@ -85,16 +93,38 @@ def configure_compile_cache() -> str:
     return path
 
 
+def _from_json(hint, value):
+    """`value`, read from JSON, as the type `hint` declares it: a
+    dataclass built from its object, field by field; a tuple from a
+    list, item by item."""
+    if value is None:
+        return None
+    if dataclasses.is_dataclass(hint) and isinstance(value, dict):
+        hints = typing.get_type_hints(hint)
+        return hint(**{k: _from_json(hints.get(k), v)
+                       for k, v in value.items()})
+    origin, args = typing.get_origin(hint), typing.get_args(hint)
+    if origin is typing.Union:
+        for arg in args:
+            if arg is not type(None):
+                out = _from_json(arg, value)
+                if out is not value:
+                    return out
+        return value
+    if origin is tuple and isinstance(value, list):
+        if len(args) == 2 and args[1] is Ellipsis:
+            args = (args[0],) * len(value)
+        return tuple(_from_json(a, v)
+                     for a, v in zip(args, value, strict=True))
+    return value
+
+
 def model_config(model):
-    """The program's ModelConfig of a configuration file's "model"."""
-    from repro.common.config import ModelConfig, SSMConfig
-    m = dict(model)
-    m["pattern"] = tuple(m["pattern"])
-    if m.get("ssm"):
-        s = dict(m["ssm"])
-        s["a_init_range"] = tuple(s["a_init_range"])
-        m["ssm"] = SSMConfig(**s)
-    return ModelConfig(**m)
+    """The program's ModelConfig of a configuration file's "model":
+    every nested config that ModelConfig declares is built from its
+    object, and every list that a tuple field holds is a tuple."""
+    from repro.common.config import ModelConfig
+    return _from_json(ModelConfig, model)
 
 
 # ---------------------------------------------------------------------------
@@ -295,6 +325,47 @@ def run_window(bench: Bench, seconds: float, trace_dir=None) -> dict:
             "compiles": clock.compiles, "compile_s": clock.seconds}
 
 
+def program_op_names(hooks, batch: int, seq: int, rows_dtype="int32"
+                     ) -> dict:
+    """{HLO module name: {instruction name: op_name}} of the hooks' two
+    round programs, from their compiled text, lowered from shapes with
+    the arguments the window passes (rows of `rows_dtype`). Lowering
+    from shapes reads no rows from the streams."""
+    import jax
+    from harness import trace as T
+    like = lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype,  # noqa: E731
+                                          sharding=x.sharding)
+    p = jax.tree.map(like, hooks.params_stk)
+    mu = jax.tree.map(like, hooks.mu_stk)
+    n = len(hooks.clients)
+    rows = jax.ShapeDtypeStruct((n, hooks.local_steps, batch, seq),
+                                rows_dtype, sharding=hooks.stacked)
+    w = jax.ShapeDtypeStruct((n,), "float32", sharding=hooks.stacked)
+    lowered = (hooks._local_fn.lower(p, mu, {"tokens": rows,
+                                             "labels": rows}),
+               hooks._avg_fn.lower(p, p, mu, mu, w))
+    return dict(T.op_names(x.compile().as_text()) for x in lowered)
+
+
+def window_op_names(hooks, batch: int, seq: int, rows_dtype="int32"
+                    ) -> dict:
+    """`program_op_names`, where its lowering finds the executables the
+    window ran in memory; {} where it compiles anything, since a new
+    executable's instruction names need not be the trace's: the scope
+    metrics then read None."""
+    from harness.clock import CompileClock
+    t0 = time.perf_counter()
+    with CompileClock() as clock:
+        programs = program_op_names(hooks, batch, seq, rows_dtype)
+    log(f"HLO text of {sorted(programs)} in "
+        f"{time.perf_counter() - t0:.3f} s, {clock.compiles} compiles, "
+        f"{clock.cache_hits} cache hits")
+    if clock.compiles:
+        log("the lowered programs are not the window's: no scope is read")
+        return {}
+    return programs
+
+
 def memory_peak(n: int) -> int:
     import jax
     return max(int((d.memory_stats() or {}).get("peak_bytes_in_use", 0))
@@ -308,14 +379,17 @@ class Record:
     """What a per-layer reader (bench/metrics/<name>.py) reads: the
     trace record (harness/trace.py) with the traced window [lo, hi] in
     its clock, the benchmark's host spans inside the window, the rounds
-    completed in it, the cell's model and traffic, and the chip's
-    published peaks."""
+    completed in it, the cell's model, traffic and reference module,
+    the chip's published peaks, and each program's op_names by
+    instruction (`program_op_names`), which `scope_ms` reads."""
 
-    def __init__(self, cell, trace, win, spans, peaks):
+    def __init__(self, cell, trace, win, spans, peaks, programs=None):
         from harness import trace as T
         self.cell = cell
         self.model, self.traffic = cell.model, cell.traffic
+        self.reference = cells.reference_module(cell)
         self.peaks = peaks
+        self.programs = programs or {}
         self.rounds = win["rounds"]
         self.window_s = win["t_close"] - win["t0"]
         t0, t1 = win["t0"] * 1e9, win["t_close"] * 1e9
@@ -327,6 +401,38 @@ class Record:
     def span_s(self, name: str) -> float:
         return sum(b - a for a, b in self.spans.get(name, ())) / 1e9
 
+    def scope_ms(self, program: str, scope=None, pass_=None):
+        """Device ms per run of `program` (an HLO module name, such as
+        "jit_local_train") of its ops whose op_name has `scope` as a
+        path component once `jvp(...)` and `transpose(...)` are
+        unwrapped, or of all its ops where `scope` is None; `pass_`
+        "forward" or "backward" keeps those under `jvp` without, or
+        with, `transpose` (harness/trace.py `in_scope`). Averaged over
+        the chips; None where no op of the window is selected, or where
+        an op in the program's runs is no instruction of its text."""
+        from harness import trace as T
+        names = self.programs.get(program)
+        if names is None or self.unknown_ops(program):
+            return None
+        picked = {n for n, op in names.items()
+                  if scope is None or T.in_scope(op, scope, pass_)}
+
+        def chip(dev):
+            runs = T.module_events(dev, program, self.lo, self.hi)
+            ns = [d for n, d in T.program_ops(dev, runs)
+                  if scope is None or n in picked]
+            return sum(ns) / len(runs) / 1e6 if ns else None
+        return self.per_chip(chip)
+
+    def unknown_ops(self, program: str) -> int:
+        """How many ops in the runs of `program`, over the chips, name no
+        instruction of its text."""
+        from harness import trace as T
+        names = self.programs.get(program, {})
+        return sum(n not in names for dev in self.devices
+                   for n, _ in T.program_ops(dev, T.module_events(
+                       dev, program, self.lo, self.hi)))
+
     def per_chip(self, fn):
         """Mean over the chips of fn(device), leaving out None; None
         where no chip gives a value."""
@@ -334,11 +440,14 @@ class Record:
         return sum(vals) / len(vals) if vals else None
 
 
-def reduce_trace(cell, trace_dir, win, spans, peaks):
+def reduce_trace(cell, trace_dir, win, spans, peaks, programs):
     import numpy as np
     from harness import trace as T
     rec = T.load_xplane(trace_dir)
-    r = Record(cell, rec, win, spans, peaks)
+    r = Record(cell, rec, win, spans, peaks, programs)
+    for prog in sorted(programs):
+        log(f"{prog}: its ops take {r.scope_ms(prog)!r} ms a run; "
+            f"{r.unknown_ops(prog)} are not in its text")
     busy = [T.busy_ns(d, r.lo, r.hi) for d in rec["devices"]]
     device = {"busy_s": float(np.mean(busy)) / 1e9 if busy else 0.0,
               "window_s": (r.hi - r.lo) / 1e9}
@@ -414,13 +523,21 @@ def run_cell(cell, seed: int, seconds: float, trace: bool,
     log(f"window: {win['rounds']} rounds in "
         f"{win['t_close'] - win['t0']:.3f} s, {win['compiles']} compiles "
         f"({win['compile_s']:.3f} s) inside it; memory_peak_bytes {mem}")
+    if win["round_times"]:
+        times = win["round_times"]
+        slow = sorted(range(len(times)), key=lambda i: -times[i])[:5]
+        log(f"round times: median {float(np.median(times)):.6f} s; "
+            f"slowest (round, s) "
+            f"{[(i, round(float(times[i]), 6)) for i in slow]}")
 
     result = {"correct": False, "attempted": win["attempted"],
               "failed": win["failed"], "metrics": {}}
     if trace:
         import shutil
+        programs = window_op_names(bench.hooks, cell.traffic["batch"],
+                                   cell.traffic["seq"])
         metrics, devinfo, breakdown = reduce_trace(
-            cell, tmp, win, spans, peaks)
+            cell, tmp, win, spans, peaks, programs)
         shutil.rmtree(tmp, ignore_errors=True)
         result["metrics"] = metrics
     else:
@@ -437,9 +554,10 @@ def run_cell(cell, seed: int, seconds: float, trace: bool,
         tr = cell.traffic
         from harness.flops import round_flops_per_client
         tokens = tr["clients"] * tr["local_steps"] * tr["batch"] * tr["seq"]
+        flops = round_flops_per_client(cells.reference_module(cell),
+                                       cell.model, tr)
         log(f"client tokens/s {tokens / values['round_s']:.1f}; model "
-            f"FLOP per client round "
-            f"{round_flops_per_client(cell.model, tr):.6g}")
+            f"FLOP per client round {flops:.6g}")
     result["device"] = {"platform": dev.platform, "kind": dev.device_kind,
                         "count": len(devices), "memory_peak_bytes": mem,
                         **devinfo}

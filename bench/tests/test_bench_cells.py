@@ -22,6 +22,7 @@ def test_cell_resolves_by_name(name):
     assert cell.traffic["clients"] == cell.chips
     ref = cells.reference_module(cell)
     assert callable(ref.param_specs) and callable(ref.loss)
+    assert callable(ref.step_flops)
     for m in cell.per_layer:
         assert callable(cells.metric_reader(m["name"]))
     assert {m["name"] for m in cell.end_to_end} >= {"setup_s", "round_s"}
