@@ -18,23 +18,57 @@ LOCAL_ATTN = "local_attn"  # sliding-window self attention
 CROSS_ATTN = "cross_attn"  # cross attention to (stub) image embeddings
 MAMBA2 = "mamba2"        # SSD state-space layer
 RGLRU = "rglru"          # Griffin recurrent block (RG-LRU)
+MOE = "moe"              # expert layer standing alone as a block's mixer
 
-SUPPORTED_KINDS = (ATTN, LOCAL_ATTN, CROSS_ATTN, MAMBA2, RGLRU)
+SUPPORTED_KINDS = (ATTN, LOCAL_ATTN, CROSS_ATTN, MAMBA2, RGLRU, MOE)
 
 
 @dataclasses.dataclass(frozen=True)
 class MoEConfig:
+    """Expert-layer hyper-parameters.
+
+    `router` "softmax" is the GShard layer: softmax top-k over groups of
+    `group_size` tokens, each expert taking at most its capacity
+    (`capacity_factor`) and dropping the rest, with a load-balancing
+    auxiliary loss. "sigmoid" is the DeepSeek-V3 / Nemotron-H router:
+    sigmoid scores, the top k of scores plus a selection-only bias
+    (`e_score_correction_bias`, no gradient), weights normalised over
+    the k chosen and scaled by `routed_scaling`; it drops no token and
+    adds no auxiliary loss.
+
+    Held share (sigmoid router): the router spans all `num_experts`,
+    and the layer holds experts 0 .. `held` - 1 (all where `held` is
+    None), computing only their part of the result for the slots routed
+    to them, as one chip of an expert-parallel deployment does without
+    its exchange (another chip's share is the same layer with the
+    router's expert order rolled). `shared_d_ff` > 0 adds a shared
+    expert that every token passes through.
+    """
     num_experts: int
     top_k: int
     d_ff: int                      # per-expert hidden dim
     capacity_factor: float = 1.25
     group_size: int = 512          # tokens per dispatch group (GShard style)
     router_jitter: float = 0.0
+    router: str = "softmax"        # softmax (GShard) | sigmoid
+    routed_scaling: float = 1.0    # sigmoid: scale of the routed weights
+    held: Optional[int] = None     # sigmoid: experts held here
+    shared_d_ff: int = 0           # width of the shared expert, 0 = none
+
+    @property
+    def num_held(self) -> int:
+        return self.num_experts if self.held is None else self.held
 
 
 @dataclasses.dataclass(frozen=True)
 class SSMConfig:
-    """Mamba2 / SSD hyper-parameters."""
+    """Mamba2 / SSD hyper-parameters.
+
+    The SSD inner width is `num_heads * head_dim` where `num_heads` is
+    set, else `expand * d_model`. B and C come in `n_groups` groups, head
+    h reading group h // (heads / n_groups), and the gated RMSNorm runs
+    over each group's share of the inner width.
+    """
     d_state: int = 128
     head_dim: int = 64
     expand: int = 2
@@ -44,6 +78,7 @@ class SSMConfig:
     dt_min: float = 0.001
     dt_max: float = 0.1
     a_init_range: Tuple[float, float] = (1.0, 16.0)
+    num_heads: Optional[int] = None
 
 
 @dataclasses.dataclass(frozen=True)
@@ -67,15 +102,16 @@ class ModelConfig:
     head_dim: Optional[int] = None     # default d_model // num_heads
     # Block pattern. A model is `num_layers` layers tiled by `pattern`;
     # remainder layers (num_layers % len(pattern)) form an explicit tail
-    # taking the pattern prefix.
+    # taking the pattern prefix. Each layer is pre-norm + mixer, plus a
+    # feed-forward sublayer where `has_mlp`.
     pattern: Tuple[str, ...] = (ATTN,)
-    # attention
-    rope_theta: float = 10_000.0
+    # attention; rope_theta None applies no rotary embedding
+    rope_theta: Optional[float] = 10_000.0
     qkv_bias: bool = False
     window_size: int = 2048            # for local_attn layers
     logit_softcap: Optional[float] = None
     # mlp
-    mlp_kind: str = "swiglu"           # swiglu|gelu
+    mlp_kind: str = "swiglu"           # swiglu|gelu|relu2
     # optional sub-configs
     moe: Optional[MoEConfig] = None
     ssm: Optional[SSMConfig] = None
@@ -129,58 +165,60 @@ class ModelConfig:
         full = set(self.pattern + self.tail_pattern)
         return ATTN not in full and CROSS_ATTN not in full
 
+    @property
+    def has_mlp(self) -> bool:
+        """Whether every layer carries a feed-forward sublayer after its
+        mixer. A pattern that names the MOE kind has its feed-forward
+        work in those layers, and the other kinds carry none."""
+        return (self.d_ff > 0 or self.moe is not None) \
+            and MOE not in self.pattern
+
     def param_count(self) -> int:
         """Analytic parameter count (matches init_params; used for 6ND)."""
-        d, dff, v = self.d_model, self.d_ff, self.vocab_size
+        d, v = self.d_model, self.vocab_size
         hd = self.resolved_head_dim
         nq, nkv = self.num_heads, self.num_kv_heads
-        counts = {}
-        counts[ATTN] = counts[LOCAL_ATTN] = (
-            d * (nq * hd) + 2 * d * (nkv * hd) + (nq * hd) * d
-            + (2 * d)  # 2 rmsnorm scales (pre-attn + pre-mlp share layer)
-        )
-        counts[CROSS_ATTN] = counts[ATTN]
+        mats = 3 if self.mlp_kind == "swiglu" else 2
+        attn = d * (nq * hd) + 2 * d * (nkv * hd) + (nq * hd) * d
         if self.qkv_bias:
-            counts[ATTN] += nq * hd + 2 * nkv * hd
-            counts[LOCAL_ATTN] = counts[CROSS_ATTN] = counts[ATTN]
+            attn += nq * hd + 2 * nkv * hd
+        mix = {ATTN: attn, LOCAL_ATTN: attn, CROSS_ATTN: attn}
+        ffn = mats * d * self.d_ff
         if self.moe is not None:
-            e, eff = self.moe.num_experts, self.moe.d_ff
-            mlp = d * e + e * (3 * d * eff if self.mlp_kind == "swiglu" else 2 * d * eff)
-        else:
-            mlp = 3 * d * dff if self.mlp_kind == "swiglu" else 2 * d * dff
-        # attention-kind layers carry the mlp too (parallel structure:
-        # every non-ssm/rglru layer = attn + mlp).
-        for k in (ATTN, LOCAL_ATTN, CROSS_ATTN):
-            counts[k] += mlp
+            m = self.moe
+            ffn = (d * m.num_experts + m.num_held * mats * d * m.d_ff
+                   + mats * d * m.shared_d_ff)
+            if m.router == "sigmoid":
+                ffn += m.num_experts                    # selection bias
+            mix[MOE] = ffn
         if self.ssm is not None:
             s = self.ssm
-            d_in = s.expand * d
+            d_in = (s.num_heads * s.head_dim if s.num_heads
+                    else s.expand * d)
             nheads = d_in // s.head_dim
             conv_dim = d_in + 2 * s.n_groups * s.d_state
-            counts[MAMBA2] = (
+            mix[MAMBA2] = (
                 d * (2 * d_in + 2 * s.n_groups * s.d_state + nheads)  # in_proj
                 + conv_dim * s.conv_width + conv_dim                    # conv1d + bias
                 + 3 * nheads                                            # A_log, dt_bias, D
                 + d_in                                                  # gated norm
-                + d_in * d + d                                          # out_proj + norm
+                + d_in * d                                              # out_proj
             )
         if self.rglru is not None:
             w = self.rglru.lru_width or d
-            counts[RGLRU] = (
-                2 * d * w            # two input branches
-                + w * self.rglru.conv_width + w   # temporal conv + bias
-                + 2 * w * w // 1     # RG-LRU input/recurrence gates (diag-block)
-                + 2 * w              # gate biases
-                + w                  # Lambda
-                + w * d              # out proj
-                + d                  # pre-norm
+            mix[RGLRU] = (
+                3 * d * w                        # two input branches, out proj
+                + w * self.rglru.conv_width + w  # temporal conv + bias
+                + 5 * w                          # gate scales, biases, Lambda
             )
+        # each layer: pre-norm + mixer [+ norm + feed-forward]
+        extra = d + (d + ffn if self.has_mlp else 0)
         total = v * d + d            # embed + final norm
         if not self.tie_embeddings:
             total += d * v
         layers = list(self.pattern) * self.n_super + list(self.tail_pattern)
         for k in layers:
-            total += counts[k]
+            total += mix[k] + extra
         return total
 
 

@@ -1,5 +1,6 @@
 """Core transformer layers: norms, RoPE, attention (GQA / sliding-window /
-cross), MLPs, and GShard capacity-routed MoE.
+cross), MLPs, GShard capacity-routed MoE, and the held share of a
+sigmoid-routed expert layer that drops no token.
 
 All layers are pure functions over param pytrees. Shapes use
 B=batch, S=query seq, T=kv seq, D=d_model, N=q heads, K=kv heads,
@@ -13,6 +14,7 @@ TPU when ``cfg.use_pallas`` is set.
 """
 from __future__ import annotations
 
+import functools
 import math
 from typing import Optional
 
@@ -222,7 +224,7 @@ def attention(p, x, cfg, *, kind, shard: ShardingCtx = INERT,
     if positions is None:
         positions = jnp.arange(S)
     kv_positions = jnp.arange(T)
-    if not cross:
+    if not cross and cfg.rope_theta is not None:
         q = rope(q, positions, cfg.rope_theta)
         k = rope(k, kv_positions, cfg.rope_theta)
 
@@ -278,8 +280,9 @@ def decode_attention(p, x, cfg, *, kind, cache, pos, shard: ShardingCtx = INERT,
         if cfg.qkv_bias:
             knew = knew + p["bk"]
             vnew = vnew + p["bv"]
-        q = rope(q, pos[:, None], cfg.rope_theta)
-        knew = rope(knew, pos[:, None], cfg.rope_theta)
+        if cfg.rope_theta is not None:
+            q = rope(q, pos[:, None], cfg.rope_theta)
+            knew = rope(knew, pos[:, None], cfg.rope_theta)
         L = cache["k"].shape[1]
         if kind == C.LOCAL_ATTN:
             # ring buffer of size window
@@ -325,13 +328,19 @@ def mlp_schema(cfg):
     }
 
 
+def _act(kind, h):
+    """The ungated MLP activation of `mlp_kind`: gelu, or relu2 (the
+    squared ReLU of Nemotron-H and Primer)."""
+    return jnp.square(jax.nn.relu(h)) if kind == "relu2" else jax.nn.gelu(h)
+
+
 def mlp(p, x, cfg, shard: ShardingCtx = INERT):
     if cfg.mlp_kind == "swiglu":
         gate = jnp.einsum("bsd,df->bsf", x, p["wi_gate"])
         up = jnp.einsum("bsd,df->bsf", x, p["wi_up"])
         h = jax.nn.silu(gate) * up
     else:
-        h = jax.nn.gelu(jnp.einsum("bsd,df->bsf", x, p["wi"]))
+        h = _act(cfg.mlp_kind, jnp.einsum("bsd,df->bsf", x, p["wi"]))
     h = shard(h, "batch", "seq", "mlp")
     y = jnp.einsum("bsf,fd->bsd", h, p["wo"])
     return shard(y, "batch", "seq", "embed_act")
@@ -341,6 +350,8 @@ def mlp(p, x, cfg, shard: ShardingCtx = INERT):
 # Mixture of Experts (GShard capacity routing, top-k).
 # ---------------------------------------------------------------------------
 def moe_schema(cfg):
+    if cfg.moe.router == "sigmoid":
+        return held_experts_schema(cfg)
     d = cfg.d_model
     e, f = cfg.moe.num_experts, cfg.moe.d_ff
     s = {"router": ParamSpec((d, e), ("embed", None))}
@@ -362,8 +373,13 @@ def moe_capacity(cfg, group_tokens: int) -> int:
 
 
 def moe(p, x, cfg, shard: ShardingCtx = INERT):
-    """x: (B,S,D). GShard one-hot dispatch with per-group capacity."""
+    """x: (B,S,D). GShard one-hot dispatch with per-group capacity; a
+    sigmoid router is the held share of `held_experts`, with no
+    auxiliary loss. Returns (y, aux loss)."""
     m = cfg.moe
+    if m.router == "sigmoid":
+        return (held_experts(p, x, cfg, shard=shard),
+                jnp.zeros((), jnp.float32))
     B, S, D = x.shape
     gs = min(m.group_size, B * S)
     assert (B * S) % gs == 0, (B, S, gs)
@@ -415,3 +431,202 @@ def _aux_loss(probs, onehot):
     frac_tokens = jnp.mean(jnp.sum(onehot, axis=2), axis=1)   # (ng, E)
     frac_probs = jnp.mean(probs, axis=1)                       # (ng, E)
     return jnp.mean(jnp.sum(frac_tokens * frac_probs, -1)) * E
+
+
+# ---------------------------------------------------------------------------
+# The held share of a sigmoid-routed expert layer (DeepSeek-V3 /
+# Nemotron-H routing), with no token dropped.
+# ---------------------------------------------------------------------------
+# `jax.named_scope` names of the layer and its parts, as a compiled op's
+# `op_name` carries them.
+EXPERT_LAYER, ROUTER, EXPERTS, SHARED_EXPERT = (
+    "expert_layer", "router", "experts", "shared_expert")
+
+
+def _normal_over(axis):
+    """Normal init with std 1/sqrt(shape[axis]): the fan-in of a stacked
+    weight is not its leading dim."""
+    def init(key, shape):
+        return (jax.random.normal(key, shape, jnp.float32)
+                / math.sqrt(shape[axis]))
+    return init
+
+
+def held_experts_schema(cfg):
+    """Router (fp32) and selection bias over all experts; the held
+    experts' ungated MLP weights; the shared expert's."""
+    d, m = cfg.d_model, cfg.moe
+    if cfg.mlp_kind == "swiglu":
+        raise ValueError("the sigmoid-routed expert layer takes an "
+                         "ungated mlp_kind (gelu, relu2)")
+    h, f = m.num_held, m.d_ff
+    s = {
+        "router": ParamSpec((d, m.num_experts), ("embed", None),
+                            dtype=jnp.float32),
+        "router_bias": ParamSpec((m.num_experts,), (None,),
+                                 dtype=jnp.float32),
+        "wi": ParamSpec((h, d, f), ("experts", "embed", "mlp"),
+                        _normal_over(1)),
+        "wo": ParamSpec((h, f, d), ("experts", "mlp", "embed"),
+                        _normal_over(1)),
+    }
+    if m.shared_d_ff:
+        fs = m.shared_d_ff
+        s["shared"] = {"wi": ParamSpec((d, fs), ("embed", "mlp")),
+                       "wo": ParamSpec((fs, d), ("mlp", "embed"))}
+    return s
+
+
+def route(p, x, m):
+    """Sigmoid routing of tokens x (T, D) over all `m.num_experts`:
+    scores s = sigmoid(x W_r) in fp32, the top k of s + b chosen (b
+    selects and gets no gradient), each weight `routed_scaling` x s over
+    the sum of s over the k chosen. Returns (chosen (T, k) expert ids,
+    weights (T, k) fp32)."""
+    logits = jnp.dot(x.astype(jnp.float32), p["router"],
+                     precision=lax.Precision.HIGHEST)
+    scores = jax.nn.sigmoid(logits)
+    _, chosen = lax.top_k(scores + lax.stop_gradient(p["router_bias"]),
+                          m.top_k)
+    w = jnp.take_along_axis(scores, chosen, axis=1)
+    return chosen, m.routed_scaling * w / jnp.sum(w, -1, keepdims=True)
+
+
+def held_slots(chosen, m):
+    """The routed slots (token, k) in the order of the held expert they
+    go to (experts 0 .. held - 1), those to experts not held here last.
+    Returns (order (T*k,) slot indices, sizes (held,) int32 slots per
+    held expert)."""
+    h = m.num_held
+    key = jnp.minimum(chosen.reshape(-1), h)
+    order = jnp.argsort(key, stable=True)
+    sizes = jnp.sum(key[:, None] == jnp.arange(h), axis=0,
+                    dtype=jnp.int32)
+    return order, sizes
+
+
+def _filled(rows, sizes):
+    """(rows, 1) mask of the rows inside the groups of `sizes`."""
+    return (jnp.arange(rows) < jnp.sum(sizes))[:, None]
+
+
+# One client slot at a time under vmap: neither the TPU compiler's
+# grouped matmul nor the megablox kernels' grid, sized by the groups,
+# take a batch dim. Rows past the groups are left unwritten by the TPU's
+# `lax.ragged_dot`, in its result and in its transpose's; they are
+# zeroed, so that memory reaches neither values nor gradients.
+@jax.custom_batching.sequential_vmap
+def _ragged(x, w, sizes):
+    return jnp.where(_filled(x.shape[0], sizes),
+                     lax.ragged_dot(x, w, sizes), 0)
+
+
+@jax.custom_batching.sequential_vmap
+def _ragged_vjp(x, w, sizes, g):
+    _, vjp = jax.vjp(lambda a, b: lax.ragged_dot(a, b, sizes), x, w)
+    dx, dw = vjp(g)
+    return jnp.where(_filled(x.shape[0], sizes), dx, 0), dw
+
+
+def _block(dim, cap):
+    """The largest multiple of 128 that divides `dim` and is at most
+    `cap`, else all of `dim`: a block edge the TPU takes."""
+    for b in range(cap - cap % 128, 0, -128):
+        if dim % b == 0:
+            return b
+    return dim
+
+
+def _gmm_tiles(m, k, n):
+    """Megablox `gmm` tiles (rows, contraction, columns): 256 rows, under
+    16 MiB of VMEM at 2688 x 1856 bf16 with double buffering."""
+    return math.gcd(m, 256), _block(k, 1024), _block(n, 1024)
+
+
+def _tgmm_tiles(m, k, n):
+    """Megablox `tgmm` tiles: each expert's (k, n) block of its weight
+    gradient, and the block's fp32 accumulator, at most 720K elements."""
+    tk = _block(k, 512)
+    return math.gcd(m, 256), tk, _block(n, max(128, 720_000 // tk))
+
+
+def _with_rest(x, sizes):
+    """The group sizes with the rows past the held groups as one more
+    group, which megablox leaves out of the work and zeroes."""
+    return jnp.append(sizes, x.shape[0] - jnp.sum(sizes)).astype(jnp.int32)
+
+
+@jax.custom_batching.sequential_vmap
+def _megablox(x, w, sizes):
+    from jax.experimental.pallas.ops.tpu.megablox.gmm import gmm
+    return gmm(x, w, _with_rest(x, sizes), x.dtype, _gmm_tiles)
+
+
+@jax.custom_batching.sequential_vmap
+def _megablox_vjp(x, w, sizes, g):
+    from jax.experimental.pallas.ops.tpu.megablox.gmm import gmm, tgmm
+    every = _with_rest(x, sizes)
+    dx = gmm(g, w, every, x.dtype, _gmm_tiles, transpose_rhs=True)
+    dw = tgmm(x.T, g, every, w.dtype, _tgmm_tiles,
+                 num_actual_groups=w.shape[0])
+    return dx, dw
+
+
+_GROUPED = {False: (_ragged, _ragged_vjp), True: (_megablox, _megablox_vjp)}
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def grouped_matmul(x, w, sizes, pallas=False):
+    """Rows of x (M, K), sorted by group, each times its group's w
+    (G, K, N), with the rows past sum(sizes) zero: `lax.ragged_dot`, or
+    with `pallas` the megablox kernels, whose work follows the rows in
+    the groups in tiles of 256."""
+    return _GROUPED[pallas][0](x, w, sizes)
+
+
+def _grouped_fwd(x, w, sizes, pallas):
+    return _GROUPED[pallas][0](x, w, sizes), (x, w, sizes)
+
+
+def _grouped_bwd(pallas, res, g):
+    x, w, sizes = res
+    dx, dw = _GROUPED[pallas][1](x, w, sizes, g)
+    return dx, dw, None
+
+
+grouped_matmul.defvjp(_grouped_fwd, _grouped_bwd)
+
+
+def held_experts(p, x, cfg, shard: ShardingCtx = INERT):
+    """This chip's part of a sigmoid-routed expert layer. x: (B,S,D).
+
+    Each token is routed over all experts (`route`); every slot routed
+    to a held expert is computed, however uneven the routing: the
+    slots are sorted by held expert and the experts' MLPs run as grouped
+    matmuls (`grouped_matmul`) over rows bounded by all B*S*k slots.
+    Slots to experts held elsewhere weigh 0; their weights still count
+    in the normalisation. The shared expert sees every token. No
+    auxiliary loss."""
+    m = cfg.moe
+    B, S, D = x.shape
+    n, k = B * S, m.top_k
+    xf = x.reshape(n, D)
+    act = functools.partial(_act, cfg.mlp_kind)
+    with jax.named_scope(EXPERT_LAYER):
+        with jax.named_scope(ROUTER):
+            chosen, w = route(p, xf, m)
+            order, sizes = held_slots(chosen, m)
+            w = jnp.where(chosen < m.num_held, w, 0.0)
+        with jax.named_scope(EXPERTS):
+            gmm = functools.partial(grouped_matmul, sizes=sizes,
+                                    pallas=cfg.use_pallas)
+            h = act(gmm(xf[order // k], p["wi"]))
+            ys = gmm(h, p["wo"])
+            ys = ys[jnp.argsort(order)].reshape(n, k, D)
+            y = jnp.sum(ys.astype(jnp.float32) * w[..., None], axis=1)
+            y = y.astype(x.dtype)
+        if m.shared_d_ff:
+            with jax.named_scope(SHARED_EXPERT):
+                sh = p["shared"]
+                y = y + act(xf @ sh["wi"]) @ sh["wo"]
+    return shard(y.reshape(B, S, D), "batch", "seq", "embed_act")

@@ -1,9 +1,12 @@
 """Unified decoder LM covering all assigned architecture families.
 
 A model is ``num_layers`` layers tiled by ``cfg.pattern`` (e.g. dense =
-("attn",), recurrentgemma = ("rglru","rglru","attn_local")); the repeated
-super-block is scanned (`lax.scan`) with stacked params so HLO size is
-O(1) in depth, and optionally rematerialized.
+("attn",), recurrentgemma = ("rglru","rglru","attn_local"), Nemotron-H
+= ("mamba2","moe",...,"attn",...)); the repeated super-block is scanned
+(`lax.scan`) with stacked params so HLO size is O(1) in depth, and each
+of its layers optionally rematerialized. A layer is pre-norm + mixer,
+plus an MLP sublayer where ``cfg.has_mlp`` (never when the pattern names
+the standalone expert layer "moe").
 
 Public API:
   param_schema / init_params / abstract_params / logical_axes
@@ -38,14 +41,12 @@ def _sublayer_schema(cfg, kind):
         sub["mix"] = S.mamba2_schema(cfg)
     elif kind == C.RGLRU:
         sub["mix"] = S.rglru_schema(cfg)
-    if _has_mlp(cfg):
+    elif kind == C.MOE:
+        sub["mix"] = L.moe_schema(cfg)
+    if cfg.has_mlp:
         sub["norm2"] = L.rms_norm_schema(cfg.d_model)
         sub["mlp"] = L.moe_schema(cfg) if cfg.moe else L.mlp_schema(cfg)
     return sub
-
-
-def _has_mlp(cfg):
-    return cfg.d_ff > 0 or cfg.moe is not None
 
 
 def _block_schema(cfg, pattern):
@@ -92,7 +93,7 @@ def param_count(cfg) -> int:
 # ---------------------------------------------------------------------------
 # Forward (train / prefill).
 # ---------------------------------------------------------------------------
-def _apply_sublayer(kind, p, x, cfg, shard, cond):
+def _apply_sublayer(kind, p, x, *, cfg, shard, cond):
     h = L.rms_norm(x, p["norm1"], cfg.norm_eps)
     # SP boundary: gather the bf16 normed tensor (NOT the fp32 norm
     # intermediate, which GSPMD otherwise picks — 2x collective bytes).
@@ -104,8 +105,10 @@ def _apply_sublayer(kind, p, x, cfg, shard, cond):
         h = S.mamba2_mix(p["mix"], h, cfg, shard=shard)
     elif kind == C.RGLRU:
         h = S.rglru_mix(p["mix"], h, cfg, shard=shard)
+    elif kind == C.MOE:
+        h, aux = L.moe(p["mix"], h, cfg, shard=shard)
     x = x + h
-    if _has_mlp(cfg):
+    if cfg.has_mlp:
         h = L.rms_norm(x, p["norm2"], cfg.norm_eps)
         h = shard(h, "batch", None, "embed_act")
         if cfg.moe:
@@ -116,11 +119,18 @@ def _apply_sublayer(kind, p, x, cfg, shard, cond):
     return x, aux
 
 
-def _apply_block(pattern, p_blk, x, cfg, shard, cond):
+def _apply_block(pattern, p_blk, x, cfg, shard, cond, remat=False):
+    """(x, aux) of one block. With `remat`, each layer is rematerialised
+    in the backward pass: the block keeps only its layers' inputs, and
+    one layer's intermediates live at a time."""
     aux = jnp.zeros((), jnp.float32)
     for i, kind in enumerate(pattern):
-        x, a = _apply_sublayer(kind, p_blk[f"{i:02d}_{kind}"], x, cfg,
-                               shard, cond)
+        layer = functools.partial(_apply_sublayer, kind, cfg=cfg,
+                                  shard=shard, cond=cond)
+        if remat:
+            layer = jax.checkpoint(
+                layer, policy=jax.checkpoint_policies.nothing_saveable)
+        x, a = layer(p_blk[f"{i:02d}_{kind}"], x)
         aux = aux + a
     return x, aux
 
@@ -143,12 +153,10 @@ def forward(params, cfg, tokens, cond=None, shard: ShardingCtx = INERT):
     if cfg.n_super > 0:
         def block(carry, p_blk):
             h, aux = carry
-            h, a = _apply_block(cfg.pattern, p_blk, h, cfg, shard, cond)
+            h, a = _apply_block(cfg.pattern, p_blk, h, cfg, shard, cond,
+                                remat=cfg.remat)
             return (h, aux + a), None
 
-        if cfg.remat:
-            block = jax.checkpoint(block,
-                                   policy=jax.checkpoint_policies.nothing_saveable)
         (x, aux_total), _ = lax.scan(block, (x, aux_total), params["blocks"])
     if cfg.tail_pattern:
         x, a = _apply_block(cfg.tail_pattern, params["tail"], x, cfg,
@@ -221,6 +229,8 @@ def _sublayer_cache_shapes(cfg, kind, batch, max_len, dtype):
             "conv": ((batch, k - 1, w), dtype, ("batch", None, "lru_width")),
             "h": ((batch, w), jnp.float32, ("batch", "lru_width")),
         }
+    if kind == C.MOE:
+        return {}
     raise ValueError(kind)
 
 
@@ -280,8 +290,11 @@ def _apply_sublayer_decode(kind, p, x, cfg, cache, pos, shard):
         h, new = S.mamba2_decode(p["mix"], h, cfg, cache, shard=shard)
     elif kind == C.RGLRU:
         h, new = S.rglru_decode(p["mix"], h, cfg, cache, shard=shard)
+    elif kind == C.MOE:
+        h, _ = L.moe(p["mix"], h, cfg, shard=shard)
+        new = cache
     x = x + h
-    if _has_mlp(cfg):
+    if cfg.has_mlp:
         h = L.rms_norm(x, p["norm2"], cfg.norm_eps)
         if cfg.moe:
             h, _ = L.moe(p["mlp"], h, cfg, shard=shard)

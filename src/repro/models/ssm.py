@@ -3,9 +3,11 @@
 Shapes: b=batch, s=seq, d=d_model, i=d_inner, h=ssm heads, p=head_dim,
 n=d_state, g=B/C groups, w=lru width, c=chunks, q=chunk len.
 
-The chunked SSD here is the pure-JAX reference; the Pallas kernel in
+The model trains through the chunked SSD here. The Pallas kernel in
 repro.kernels.ssd implements the identical chunk decomposition with VMEM
-tiling and is validated against `ssd_reference` below.
+tiling, forward only, and is validated against `ssd_reference` below;
+having no backward, it is not called from the model, whose `use_pallas`
+turns on the flash-attention and RG-LRU kernels.
 """
 from __future__ import annotations
 
@@ -22,9 +24,14 @@ from repro.sharding.rules import ShardingCtx, INERT
 # ===========================================================================
 # Mamba2 (SSD).
 # ===========================================================================
+# `jax.named_scope` name of the Mamba-2 mixer, as a compiled op's
+# `op_name` carries it.
+SSM_MIXER = "ssm_mixer"
+
+
 def mamba2_dims(cfg):
     s = cfg.ssm
-    d_in = s.expand * cfg.d_model
+    d_in = s.num_heads * s.head_dim if s.num_heads else s.expand * cfg.d_model
     nheads = d_in // s.head_dim
     conv_dim = d_in + 2 * s.n_groups * s.d_state
     return d_in, nheads, conv_dim
@@ -129,8 +136,28 @@ def ssd_reference(xbar, log_a, Bm, Cm, chunk, initial_state=None):
     return y.astype(xbar.dtype), final
 
 
+def gated_norm(y, z, scale, n_groups, eps):
+    """RMSNorm of y * silu(z) over each of `n_groups` equal groups of the
+    last dim (gate first, then norm, then the scale), with fp32
+    statistics as `rms_norm` keeps them; one group is the whole width."""
+    g = y * jax.nn.silu(z)
+    if n_groups == 1:      # one group: rms_norm, as the mamba2 slot runs it
+        return rms_norm(g, {"scale": scale}, eps)
+    shape = g.shape
+    g = g.reshape(shape[:-1] + (n_groups, shape[-1] // n_groups))
+    var = jnp.mean(jnp.square(g.astype(jnp.float32)), axis=-1,
+                   keepdims=True)
+    g = (g * lax.rsqrt(var + eps).astype(g.dtype)).reshape(shape)
+    return g * scale.astype(g.dtype)
+
+
 def mamba2_mix(p, x, cfg, shard: ShardingCtx = INERT):
     """Full Mamba2 mixing layer. x: (b,s,d) -> (b,s,d)."""
+    with jax.named_scope(SSM_MIXER):
+        return _mamba2_mix(p, x, cfg, shard)
+
+
+def _mamba2_mix(p, x, cfg, shard):
     s_cfg = cfg.ssm
     b, s, d = x.shape
     d_in, nh, conv_dim = mamba2_dims(cfg)
@@ -160,15 +187,10 @@ def mamba2_mix(p, x, cfg, shard: ShardingCtx = INERT):
 
     xbar = xh * dt[..., None].astype(xh.dtype)
     log_a = dt * A
-    if cfg.use_pallas:
-        from repro.kernels.ssd import ops as ssd_ops
-        y, _ = ssd_ops.ssd(xbar, log_a, Bh, Ch, chunk=s_cfg.chunk_size)
-    else:
-        y, _ = ssd_reference(xbar, log_a, Bh, Ch,
-                             chunk=min(s_cfg.chunk_size, s))
+    y, _ = ssd_reference(xbar, log_a, Bh, Ch, chunk=min(s_cfg.chunk_size, s))
     y = y + xh * p["D"][:, None].astype(y.dtype)
     y = y.reshape(b, s, d_in)
-    y = rms_norm(y * jax.nn.silu(z), {"scale": p["norm"]}, cfg.norm_eps)
+    y = gated_norm(y, z, p["norm"], s_cfg.n_groups, cfg.norm_eps)
     y = shard(y, "batch", "seq", "ssm_inner")
     out = jnp.einsum("bsi,id->bsd", y, p["wo"])
     return shard(out, "batch", "seq", "embed_act")
@@ -220,8 +242,7 @@ def mamba2_decode(p, x, cfg, state, shard: ShardingCtx = INERT):
     y = jnp.einsum("bhpn,bhn->bhp", new_ssm, Ch.astype(jnp.float32))
     y = y + xh * p["D"][:, None]
     y = y.reshape(b, 1, d_in).astype(x.dtype)
-    y = rms_norm(y * jax.nn.silu(z)[:, None], {"scale": p["norm"]},
-                 cfg.norm_eps)
+    y = gated_norm(y, z[:, None], p["norm"], s_cfg.n_groups, cfg.norm_eps)
     out = jnp.einsum("bsi,id->bsd", y, p["wo"])
     return out, {"conv": new_conv, "ssm": new_ssm}
 

@@ -1,5 +1,6 @@
-"""Compiles for a described TPU v5e: the four Pallas kernels at published
-widths and the one-chip FL round program of the phi3-mini cut.
+"""Compiles for a described TPU v5e: the four Pallas kernels and the held
+experts' grouped matmuls at published widths, and the one-chip FL round
+programs of the phi3-mini and Nemotron 3 Nano cuts.
 
 Nothing runs: the TPU compiler, which is installed without a chip,
 refuses what the chip would refuse (block shapes off the (8, 128) tiling,
@@ -148,3 +149,70 @@ def test_phi3_fedavg_keeps_the_benchmark_hooks(phi3_round):
     for prefix in ("vmap_jit_quantize", "vmap_jit_dequantize"):
         assert sum(c.startswith(prefix) for c in calls) == \
             phi3_round["leaves"], (prefix, calls)
+
+
+def test_expert_layer_nemotron_width(one_chip):
+    """The held share of a Nemotron 3 Nano expert layer, forward and
+    backward, at 2 x 2048 tokens: 8 of 128 experts of 2688 -> 1856, top
+    6, and the shared expert of 3712. The grouped matmuls lower to the
+    megablox kernels inside the `expert_layer` scope, over every token's
+    6 slots, and the weight gradients come out per held expert."""
+    from repro.configs.nemotron3_nano_30b import ONE_CHIP
+    from repro.models import layers as L
+    p = jax.tree.map(lambda s: _shape(one_chip, s.shape,
+                                      s.dtype or jnp.bfloat16),
+                     L.held_experts_schema(ONE_CHIP), is_leaf=L.is_spec)
+    x = _shape(one_chip, (2, 2048, 2688), jnp.bfloat16)
+
+    def loss(p, x):
+        y = L.held_experts(p, x, ONE_CHIP)
+        return jnp.sum(y.astype(jnp.float32))
+
+    text = _compiled_text(jax.grad(loss, argnums=(0, 1)), p, x)
+    for name, shape in (("gmm", "24576,1856"), ("gmm", "24576,2688"),
+                        ("tgmm", "8,2688,1856"), ("tgmm", "8,1856,2688")):
+        assert re.search(rf"%{name}[.\d]* = bf16\[{shape}\].*op_name="
+                         rf'"[^"]*expert_layer[^"]*"', text), (name, shape)
+
+
+@pytest.fixture(scope="module")
+def nemotron_round(topo):
+    """Both round programs of the one-chip Nemotron 3 Nano cut (fp32
+    FedAvg), compiled for the described v5e."""
+    from repro.configs.nemotron3_nano_30b import (ONE_CHIP, ONE_CHIP_BATCH,
+                                                  ONE_CHIP_LOCAL_STEPS,
+                                                  ONE_CHIP_SEQ)
+    from repro.fl.training import make_round_programs
+    from repro.models import lm
+    mesh = jax.sharding.Mesh(np.array(topo.devices[:1]), ("pod",),
+                             axis_types=(jax.sharding.AxisType.Auto,))
+    stk = NamedSharding(mesh, P("pod"))
+    local, fedavg = make_round_programs(ONE_CHIP, mesh, lr=5e-3,
+                                        quantize=False, use_pallas=True)
+    params = jax.tree.map(lambda a: _shape(stk, (1,) + a.shape, a.dtype),
+                          lm.abstract_params(ONE_CHIP))
+    mu = jax.tree.map(lambda a: _shape(stk, a.shape, jnp.float32), params)
+    batches = {k: _shape(stk, (1, ONE_CHIP_LOCAL_STEPS, ONE_CHIP_BATCH,
+                               ONE_CHIP_SEQ), jnp.int32)
+               for k in ("tokens", "labels")}
+    w = _shape(stk, (1,), jnp.float32)
+    return {"local": local.lower(params, mu, batches).compile(),
+            "fedavg": fedavg.lower(params, params, mu, mu, w).compile()}
+
+
+def test_nemotron_one_chip_round_fits(nemotron_round):
+    """Both round programs of the one-chip cut compile, leave the HBM
+    share the cut was sized for, and keep the names the benchmark reads:
+    the module `jit_local_train` with the flash-attention kernel and
+    the grouped-matmul kernels, and `jit_fedavg`."""
+    from repro.launch.roofline import V5E
+    local = nemotron_round["local"].as_text()
+    assert local.startswith("HloModule jit_local_train")
+    assert "flash_attention" in local and "%tgmm" in local
+    assert nemotron_round["fedavg"].as_text().startswith(
+        "HloModule jit_fedavg")
+    for compiled in nemotron_round.values():
+        m = compiled.memory_analysis()
+        used = (m.argument_size_in_bytes + m.output_size_in_bytes
+                + m.temp_size_in_bytes - m.alias_size_in_bytes)
+        assert used <= V5E.hbm_bytes - MIN_FREE_BYTES, used

@@ -140,3 +140,17 @@ def test_long_context_skips_rule():
     assert ("phi3-mini-3.8b", "long_500k") not in cells
     assert len(cells) == 32
     assert len(configs.skipped_cells()) == 8
+
+
+@pytest.mark.parametrize("smoke", [False, True], ids=["full", "smoke"])
+@pytest.mark.parametrize("arch", configs.ARCH_IDS + ["nemotron-3-nano"])
+def test_analytic_param_count_matches_tree(arch, smoke):
+    """ModelConfig.param_count counts the parameter tree lm builds: a
+    feed-forward sublayer on every kind where `has_mlp`, none beside a
+    standalone expert layer."""
+    if arch == "nemotron-3-nano":
+        from repro.configs import nemotron3_nano_30b as N
+        cfg = N.SMOKE if smoke else N.FULL
+    else:
+        cfg = configs.get_config(arch, smoke=smoke)
+    assert cfg.param_count() == lm.param_count(cfg)
