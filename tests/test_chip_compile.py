@@ -65,6 +65,33 @@ def test_flash_attention_phi3_width(one_chip):
     assert "tpu_custom_call" in text
 
 
+@pytest.mark.parametrize("shape", [
+    (1, 2048, 32, 96),       # phi3-mini
+    (2, 2048, 32, 128),      # Nemotron 3 Nano: kv repeated to 32 heads
+])
+def test_flash_attention_grad_widths(one_chip, shape):
+    """The gradient runs the forward kernel and the backward kernel pair,
+    and nothing of size S x S: the backward's calls are named after
+    their own wrappers, so a name starting with `flash_attention` (which
+    the benchmark counts as a forward call) marks the forward alone."""
+    from repro.kernels.flash_attention.ops import flash_attention
+    qkv = _shape(one_chip, shape, jnp.bfloat16)
+
+    def loss(q, k, v):
+        return jnp.sum(flash_attention(q, k, v).astype(jnp.float32) ** 2)
+
+    text = _compiled_text(jax.grad(loss, argnums=(0, 1, 2)), qkv, qkv, qkv)
+    calls = re.findall(r"\n\s*(?:ROOT )?%?([\w.\-]+) = [^\n]*"
+                       r'custom_call_target="tpu_custom_call"', text)
+    bwd = sorted(re.sub(r"(\.\d+)+$", "", c) for c in calls
+                 if "fa_bwd" in c)
+    assert bwd == ["fa_bwd_dkv", "fa_bwd_dq"], calls
+    assert len(calls) == 3, calls
+    assert not any(c.startswith("flash_attention") for c in bwd)
+    S = shape[1]
+    assert not re.search(rf"\[(\d+,)*{S},{S}\]", text)
+
+
 def test_ssd_mamba2_width(one_chip):
     from repro.kernels.ssd.ops import ssd
     b, s, h, p, n = 1, 2048, 64, 64, 128

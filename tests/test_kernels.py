@@ -229,8 +229,8 @@ class TestGradQuant:
 
 class TestFlashAttentionGrad:
     def test_grad_matches_reference(self):
-        """use_pallas=True must be trainable: VJP through the kernel
-        matches grads of the pure reference."""
+        """use_pallas=True must be trainable: the backward kernel pair's
+        gradients match those of the pure reference."""
         rng = np.random.RandomState(21)
         B, S, N, H = 1, 128, 2, 32
         q, k, v = (jnp.asarray(rng.randn(B, S, N, H), jnp.float32)
@@ -250,6 +250,90 @@ class TestFlashAttentionGrad:
         for a, b in zip(gk, gr):
             np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                        atol=3e-4, rtol=3e-4)
+
+    @pytest.mark.parametrize(
+        "S,T,kv_heads,dtype,block_q,block_k,causal,window,softcap", [
+            # blocks under S, a q block unlike the k block
+            (128, 128, 2, jnp.float32, 32, 64, True, None, None),
+            (128, 128, 2, jnp.bfloat16, 64, 32, True, None, None),
+            # a window under a block: live tiles that differ per row of
+            # blocks, whole tiles dead on both sides of the diagonal
+            (256, 256, 2, jnp.float32, 64, 64, True, 20, None),
+            (256, 256, 2, jnp.float32, 32, 128, True, 48, None),
+            (256, 256, 2, jnp.bfloat16, 128, 32, True, 40, None),
+            (128, 128, 2, jnp.float32, 32, 32, False, 40, None),
+            # the cap's tanh derivative, alone and with a window
+            (128, 128, 2, jnp.float32, 32, 64, True, None, 5.0),
+            (128, 128, 2, jnp.bfloat16, 64, 64, True, 24, 5.0),
+            # kv repeated to the q heads, as the attention layer does
+            (128, 128, 1, jnp.float32, 32, 32, True, None, None),
+            (128, 128, 1, jnp.bfloat16, 64, 32, True, 50, 30.0),
+            # T != S
+            (64, 128, 2, jnp.float32, 32, 64, True, None, None),
+            (128, 64, 2, jnp.float32, 64, 32, True, None, None),
+            (64, 128, 2, jnp.float32, 32, 32, False, None, None),
+        ])
+    def test_kernel_grads_match_reference_vjp(
+            self, S, T, kv_heads, dtype, block_q, block_k, causal, window,
+            softcap):
+        """dq, dk and dv of the kernel pair against jax.vjp of the
+        reference on the same inputs and cotangent: within fp32
+        rounding for fp32 inputs, within two bf16 steps of the largest
+        gradient for bf16 ones (both round their results to bf16)."""
+        rng = np.random.RandomState(S + T + block_q + 7 * block_k)
+        B, N, H = 1, 4, 32
+        q, g = (jnp.asarray(rng.randn(B, S, N, H), dtype) for _ in range(2))
+        k, v = (jnp.asarray(rng.randn(B, T, kv_heads, H), dtype)
+                for _ in range(2))
+        opts = dict(causal=causal, window=window, softcap=softcap)
+        rep = lambda x: jnp.repeat(x, N // kv_heads, axis=2)
+
+        def kernel(q, k, v):
+            return flash_attention(q, rep(k), rep(v), block_q=block_q,
+                                   block_k=block_k, interpret=True, **opts)
+
+        def ref(q, k, v):
+            o = reference_attention(_fold(q), _fold(rep(k)), _fold(rep(v)),
+                                    **opts)
+            return o.reshape(B, N, S, H).transpose(0, 2, 1, 3)
+
+        out, vjp_kernel = jax.vjp(kernel, q, k, v)
+        out_ref, vjp_ref = jax.vjp(ref, q, k, v)
+        tol = 8e-3 if dtype == jnp.bfloat16 else 2e-6
+        for name, got, want in zip(("out", "dq", "dk", "dv"),
+                                   (out,) + vjp_kernel(g),
+                                   (out_ref,) + vjp_ref(g)):
+            assert got.dtype == want.dtype, name
+            got, want = (np.asarray(x, np.float32) for x in (got, want))
+            err = np.max(np.abs(got - want)) / np.max(np.abs(want))
+            assert err <= tol, (name, err)
+
+    @pytest.mark.parametrize("causal,window,softcap", [
+        (True, None, None), (True, 24, 5.0), (False, None, None)])
+    def test_forward_lse(self, causal, window, softcap):
+        """The forward's second output is each row's log-sum-exp of the
+        scaled, capped and masked scores."""
+        from repro.kernels.flash_attention.kernel import flash_attention_bnh
+        rng = np.random.RandomState(3)
+        BN, S, H = 2, 128, 32
+        q, k, v = (jnp.asarray(rng.randn(BN, S, H), jnp.float32)
+                   for _ in range(3))
+        _, lse = flash_attention_bnh(q, k, v, causal=causal, window=window,
+                                     softcap=softcap, block_q=32,
+                                     block_k=64, interpret=True)
+        s = jnp.einsum("bsh,bth->bst", q, k) / np.sqrt(H)
+        if softcap is not None:
+            s = softcap * jnp.tanh(s / softcap)
+        pos = np.arange(S)
+        mask = np.ones((S, S), bool)
+        if causal:
+            mask &= pos[None, :] <= pos[:, None]
+        if window is not None:
+            mask &= pos[None, :] > pos[:, None] - window
+        want = jax.nn.logsumexp(jnp.where(mask, s, -jnp.inf), axis=-1)
+        assert lse.shape == (BN, 1, S) and lse.dtype == jnp.float32
+        np.testing.assert_allclose(np.asarray(lse[:, 0]), np.asarray(want),
+                                   atol=1e-5, rtol=1e-5)
 
     def test_model_trains_with_pallas_attention(self):
         """End-to-end: a smoke transformer takes a grad step with
