@@ -1,12 +1,12 @@
-"""Benchmark driver: one section per paper table/figure + the roofline
-report. Prints ``name,value,derived`` CSV blocks.
+"""Benchmark driver: one section per paper table/figure and the
+beyond-paper studies. Prints ``name,value,derived`` CSV blocks.
 
   table1   — Table I cost comparison (4 datasets x 3 policies)
   fig4     — client-state timeline (Fed-ISIC2019)
   fig5     — cumulative per-client costs (Fed-ISIC2019)
   scaling  — fleet-core wall/RSS curve (BENCH_scaling.json) + the
              beyond-paper savings-vs-skew study
-  roofline — per (arch x shape x mesh) roofline terms from the dry-run
+  preemption — robustness vs spot preemption rate
 """
 from __future__ import annotations
 
@@ -19,7 +19,7 @@ def section(title):
 
 def main() -> None:
     want = sys.argv[1:] or ["table1", "fig4", "fig5", "scaling",
-                        "preemption", "roofline"]
+                            "preemption"]
 
     if "table1" in want:
         section("Table I: cost & savings across datasets and policies")
@@ -47,11 +47,6 @@ def main() -> None:
         section("Beyond-paper: robustness vs spot preemption rate")
         from benchmarks import preemption_sweep
         preemption_sweep.main()
-
-    if "roofline" in want:
-        section("Roofline: per (arch x shape x mesh) terms from dry-run")
-        from benchmarks import roofline_report
-        roofline_report.main()
 
 
 if __name__ == "__main__":

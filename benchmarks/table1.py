@@ -2,7 +2,7 @@
 (Fed-ISIC2019, AI-READI, CIFAR-10, MNIST) x (FedCostAware, Spot, On-demand).
 
 Client heterogeneity profiles are derived from the paper's own cost
-identities (documented in EXPERIMENTS.md §Repro-Table1):
+identities:
 
   makespan        = od_total / (n_clients * od_rate)
   slowest epoch   ~ (makespan - spin_up) / n_epochs

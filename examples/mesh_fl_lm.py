@@ -1,75 +1,43 @@
-"""FL-in-the-mesh: federated training of a transformer LM where each
-'pod' of a device mesh hosts one FL client (DESIGN.md §2's TPU-idiomatic
-mapping of the paper's client/server pattern).
+"""FL in the mesh: federated training of a transformer LM where each
+device of a one-axis `pod` mesh hosts one FL client slot.
 
-On CPU this runs a (pod=2, data=1, model=1) toy mesh on two host
-devices; on a real multi-pod TPU deployment the same code runs the
-production (2,16,16) mesh. Local steps touch no cross-pod axis; the
-synchronous FedAvg barrier is one weighted collective — optionally int8
-ring-compressed (4x less cross-pod traffic).
+`MeshTrainerHooks` builds the two round programs the FL runner and the
+chip benchmark run: local training, in which no slot talks to another,
+and the FedAvg barrier, one weighted `psum` over `pod`. This drives
+them by hand for two clients whose data weigh 3:1.
+
+On CPU the two devices come from the host:
 
     XLA_FLAGS=--xla_force_host_platform_device_count=2 JAX_PLATFORMS=cpu \
         PYTHONPATH=src python examples/mesh_fl_lm.py
 """
 import jax
-import jax.numpy as jnp
 import numpy as np
 
-from repro import configs
-from repro.data.synthetic import token_stream
-from repro.fl import mesh_fl
-from repro.models import lm
-from repro.sharding import rules as R
+from repro.fl.training import MeshTrainerHooks
 
-N_CLIENTS = 2
-LOCAL_STEPS = 4
-ROUNDS = 6
-B_LOCAL, SEQ = 8, 32
+CLIENTS = ("client_0", "client_1")
+WEIGHTS = np.asarray([3.0, 1.0], np.float32)   # client 0 has 3x the data
+ROUNDS = 4
 
-if jax.device_count() < N_CLIENTS:
-    raise SystemExit(f"needs {N_CLIENTS} devices, found {jax.device_count()}: "
-                     "set XLA_FLAGS=--xla_force_host_platform_device_count=2")
-mesh = jax.make_mesh((N_CLIENTS, 1, 1), ("pod", "data", "model"),
-                     axis_types=(jax.sharding.AxisType.Auto,) * 3,
-                     devices=jax.devices()[:N_CLIENTS])
-rules = R.make_rules("train")
-shard = R.ShardingCtx(mesh, rules)
+if jax.device_count() < len(CLIENTS):
+    raise SystemExit(f"needs {len(CLIENTS)} devices, found "
+                     f"{jax.device_count()}: set "
+                     "XLA_FLAGS=--xla_force_host_platform_device_count=2")
 
-cfg = configs.get_config("phi3-mini-3.8b", smoke=True)
-params = lm.init_params(cfg, jax.random.PRNGKey(0))
-params_stk = mesh_fl.stack_params_for_clients(params, N_CLIENTS)
-mu_stk = jax.tree.map(lambda p: jnp.zeros(p.shape, jnp.float32), params_stk)
-weights = jnp.asarray([3.0, 1.0])      # client 0 has 3x the data
+# the phi3-mini smoke config, one client slot per device
+hooks = MeshTrainerHooks(CLIENTS, local_steps=4, batch=8, seq=32, lr=5e-3)
 
-round_step = mesh_fl.make_fl_round_step(
-    cfg, opt=5e-3, shard=shard, local_steps=LOCAL_STEPS,
-    compressed=False, mesh=mesh, n_pods=N_CLIENTS)
-round_step = jax.jit(round_step)
+for r in range(ROUNDS):
+    new_p, new_mu, losses = hooks.local_round(hooks.next_batches())
+    hooks.params_stk, hooks.mu_stk = hooks.fedavg(new_p, new_mu, WEIGHTS)
+    print(f"round {r}: per-client mean loss = "
+          + ", ".join(f"{l:.3f}" for l in np.asarray(losses).mean(axis=1)))
 
-streams = [token_stream(cfg.vocab_size, B_LOCAL, SEQ, seed=i)
-           for i in range(N_CLIENTS)]
-
-with jax.set_mesh(mesh):
-    for r in range(ROUNDS):
-        batch = {
-            "tokens": jnp.stack([
-                np.stack([next(streams[c])["tokens"]
-                          for _ in range(LOCAL_STEPS)])
-                for c in range(N_CLIENTS)]),
-            "labels": jnp.stack([
-                np.stack([next(streams[c])["labels"]
-                          for _ in range(LOCAL_STEPS)])
-                for c in range(N_CLIENTS)]),
-        }
-        params_stk, mu_stk, losses = round_step(params_stk, mu_stk,
-                                                batch, weights)
-        print(f"round {r}: per-client loss = "
-              + ", ".join(f"{float(l):.3f}" for l in losses))
-
-# all clients hold the identical aggregated model after the sync barrier
-leaves = jax.tree.leaves(params_stk)
-drift = max(float(jnp.max(jnp.abs(l[0] - l[1]))) for l in leaves)
-print(f"max cross-client param drift after FedAvg barrier: {drift:.2e}")
-assert drift < 1e-5
-print("OK: synchronous FL-in-the-mesh converged with a single collective "
-      "as the round barrier.")
+# every client slot holds the identical global model after the barrier
+drift = max(float(np.max(np.abs(np.asarray(l[0], np.float32)
+                                - np.asarray(l[1], np.float32))))
+            for l in jax.tree.leaves(hooks.params_stk))
+print(f"max cross-client param drift after the FedAvg barrier: {drift:.2e}")
+assert drift == 0.0
+print("OK: the FedAvg barrier leaves every client with the same model.")

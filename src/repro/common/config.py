@@ -127,10 +127,10 @@ class ModelConfig:
     param_dtype: str = "bfloat16"
     remat: bool = True
     scan_layers: bool = True
-    use_pallas: bool = False           # TPU path; dry-run/CPU uses refs
+    use_pallas: bool = False           # TPU kernels; CPU uses the refs
     attn_chunk: int = 1024             # query-chunk for online-softmax attn
-    # per-arch logical->mesh rule overrides (e.g. granite's 40 experts do
-    # not divide a 16-way axis: shard the expert FFN dim instead)
+    # no program code reads this; it stays while
+    # bench/configs/nemotron-3-nano-30b-a3b-slot.json restates it
     sharding_overrides: Optional[Tuple[Tuple[str, Optional[Tuple[str, ...]]], ...]] = None
 
     # ------------------------------------------------------------------

@@ -3,8 +3,8 @@
 One FL round uploads one model-sized update per participating client,
 and the dollars/seconds that upload costs scale with its byte size —
 the knob practitioners actually control (FeatureCloud, Multi-FedLS).
-This module turns a param pytree (or a `ModelConfig`, via the same
-abstract shapes `configs/shapes.py` dry-runs against) into an
+This module turns a param pytree (or a `ModelConfig`, via its abstract
+parameter shapes, `models.lm.abstract_params`) into an
 `UpdatePayload` with an exact byte count for the two wire formats the
 bridge supports:
 
@@ -102,7 +102,7 @@ class UpdatePayload:
     def from_model_config(cls, cfg: Any,
                           quantized: bool = False) -> "UpdatePayload":
         """Size an update for a `ModelConfig` without materializing
-        params, via the same abstract pytree the dry-run harness uses
+        params, via its abstract parameter pytree
         (`models.lm.abstract_params`)."""
         from repro.models import lm
         return cls.from_tree(lm.abstract_params(cfg), quantized=quantized)

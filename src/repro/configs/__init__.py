@@ -1,9 +1,9 @@
 """Architecture registry: ``--arch <id>`` resolves here.
 
-Each module exposes FULL (the published config, exercised only via the
-AOT dry-run) and SMOKE (a reduced same-family config that trains a real
-step on CPU in the tests). `nemotron3_nano_30b` is not registered: no
-dry-run covers it, and its one-chip cut runs in the chip benchmark.
+Each module exposes FULL (the published config) and SMOKE (a reduced
+same-family config that trains a real step on CPU in the tests).
+`nemotron3_nano_30b` is not registered: its one-chip cut runs in the
+chip benchmark.
 """
 from __future__ import annotations
 
@@ -36,7 +36,7 @@ def get_config(arch: str, smoke: bool = False) -> ModelConfig:
 
 
 def applicable_shapes(arch: str) -> List[str]:
-    """The assigned shape set, minus rule-based skips (DESIGN.md par.4):
+    """The assigned shape set, minus rule-based skips:
     long_500k only for sub-quadratic (SSM / hybrid) architectures."""
     cfg = get_config(arch)
     out = []
@@ -48,7 +48,7 @@ def applicable_shapes(arch: str) -> List[str]:
 
 
 def all_cells():
-    """Every (arch, shape) dry-run cell after rule-based skips."""
+    """Every (arch, shape) cell after rule-based skips."""
     return [(a, s) for a in ARCH_IDS for s in applicable_shapes(a)]
 
 

@@ -2,7 +2,7 @@
 [hf:ibm-granite/granite-3.0-1b-a400m-base; hf]
 32L d_model=1536 24H kv=8 d_ff=512(per-expert) vocab=49155.
 Small experts => GShard dispatch overhead matters; group_size=128 keeps
-the dispatch einsum <10% of expert FLOPs (see DESIGN.md).
+the dispatch einsum <10% of expert FLOPs.
 """
 from repro.common.config import ModelConfig, MoEConfig, ATTN
 
@@ -13,9 +13,6 @@ FULL = ModelConfig(
     pattern=(ATTN,), mlp_kind="swiglu",
     moe=MoEConfig(num_experts=40, top_k=8, d_ff=512, capacity_factor=1.25,
                   group_size=128),
-    # 40 experts do not divide the 16-way model axis; the shape-aware rule
-    # resolver drops the expert mapping automatically, and `mlp` stays on
-    # `model` -> intra-expert TP (noted in DESIGN.md §Arch-applicability).
 )
 
 SMOKE = ModelConfig(

@@ -1,7 +1,7 @@
 """llama-3.2-vision-90b [vlm] — cross-attention image layers every 5th.
 [hf:meta-llama/Llama-3.2-11B-Vision; unverified]
 100L d_model=8192 64H kv=8 d_ff=28672 vocab=128256.
-Vision frontend is a stub: input_specs() supplies precomputed patch
+Vision frontend is a stub: the batch's "cond" holds precomputed patch
 embeddings (n_cond_tokens x d_model) consumed by the cross-attn layers.
 """
 from repro.common.config import ModelConfig, ATTN, CROSS_ATTN

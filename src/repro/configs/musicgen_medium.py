@@ -1,6 +1,6 @@
 """musicgen-medium [audio] — decoder-only over EnCodec tokens.
 [arXiv:2306.05284; hf]  48L d_model=1536 24H kv=24(MHA) d_ff=6144 vocab=2048.
-The EnCodec frontend is a stub: input_specs() provides precomputed frame
+The EnCodec frontend is a stub: the batch's "tokens" are precomputed frame
 embeddings (B,S,d_model); the LM head predicts the 2048-way codebook.
 """
 from repro.common.config import ModelConfig, ATTN

@@ -8,7 +8,7 @@ scaling 2.5, one shared expert of 3712; squared-ReLU MLPs; vocab 131072,
 untied head.
 
 Each block is x + mixer(RMSNorm(x)) with one mixer: no layer carries a
-separate MLP. Not in the dry-run registry (`ARCH_IDS`): the one-chip cut
+separate MLP. Not in the registry (`ARCH_IDS`): the one-chip cut
 below is what runs, in the chip benchmark
 (bench/configs/nemotron-3-nano-30b-a3b-slot.json restates it).
 """

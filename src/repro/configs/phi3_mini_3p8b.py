@@ -10,17 +10,6 @@ FULL = ModelConfig(
     num_layers=32, d_model=3072, num_heads=32, num_kv_heads=32,
     d_ff=8192, vocab_size=32064,
     pattern=(ATTN,), mlp_kind="swiglu", rope_theta=10_000.0,
-    # §Perf hillclimb #1: a 3.8B model on 256 chips is collective-bound
-    # under TP16+SP (peak fraction 0.096); pure ZeRO-3/FSDP (batch over
-    # all 256 devices, weights gathered per layer) is 8.4x cheaper on
-    # collectives -> peak fraction 0.75. remat stays ON (refuted attempt:
-    # remat=False -> 203GB temp, attention internals unsharded under FSDP).
-    sharding_overrides=(
-        ("batch", ("pod", "data", "model")),
-        ("embed", ("data", "model")),
-        ("heads", None), ("kv_heads", None), ("mlp", None),
-        ("vocab", None), ("seq", None),
-    ),
 )
 
 SMOKE = ModelConfig(
@@ -40,9 +29,11 @@ SMOKE = ModelConfig(
 #     (~763M params) peak at 12.71 GB in the local-training program;
 #     6 layers peak at 14.32 GB.
 #   * batch 1 x sequence 2048 per local step (half of phi3-mini's 4K
-#     context): the flash-attention backward recomputes through the
-#     reference, which holds batch x 32 heads x seq^2 fp32 scores, so
-#     seq 4096 adds ~3.5 GB and batch 2 ~1.3 GB of temporaries.
+#     context), as the benchmark's phi3 slot runs it. This was sized
+#     when the flash-attention backward recomputed through the
+#     reference's batch x 32 heads x seq^2 fp32 scores; the backward is
+#     now a Pallas kernel pair that holds no S x S scores, and a longer
+#     sequence or a larger batch has not been re-sized since.
 #   * 2 local steps per FL round, one client per chip.
 ONE_CHIP = dataclasses.replace(FULL, name="phi3-mini-3.8b-1chip",
                                num_layers=5, use_pallas=True)

@@ -15,7 +15,7 @@ computation by its execution multiplicity. It reports:
                       all-reduce / all-to-all / collective-permute count
                       result bytes; reduce-scatter counts operand bytes)
 
-Validated against analytic 6ND expectations in tests/test_roofline.py.
+Checked against hand-counted HLO in tests/test_roofline.py.
 """
 from __future__ import annotations
 

@@ -1,5 +1,5 @@
-"""Training driver: runs real steps of any ``--arch`` (smoke scale on CPU,
-full scale on a TPU mesh) with checkpoint/restart fault tolerance.
+"""Training driver: runs real AdamW steps of any ``--arch`` on one
+device, with checkpoint/restart fault tolerance.
 
   PYTHONPATH=src python -m repro.launch.train --arch phi3-mini-3.8b \\
       --smoke --steps 50 --batch 8 --seq 64 --ckpt-dir /tmp/ckpt
@@ -21,9 +21,43 @@ from repro.checkpoint.ckpt import Checkpointer
 from repro.checkpoint.store import FileStore
 from repro.common.compile_cache import enable_compile_cache
 from repro.data.synthetic import token_stream
-from repro.launch import steps as ST
 from repro.models import lm
 from repro.optim import optimizers
+
+
+def make_train_step(cfg, lr: float):
+    """An AdamW train step `(params, opt_state, batch) -> (params,
+    opt_state, {"loss"})`, and its optimizer. With `cfg.grad_accum` > 1
+    the batch is split into that many microbatches, scanned, and their
+    gradients averaged."""
+    opt = optimizers.adamw(lr=lr, weight_decay=0.1)
+    accum = max(cfg.grad_accum, 1)
+
+    def train_step(params, opt_state, batch):
+        if accum == 1:
+            loss, grads = jax.value_and_grad(
+                lambda p: lm.loss_fn(p, cfg, batch))(params)
+        else:
+            micro = jax.tree.map(
+                lambda x: x.reshape((accum, x.shape[0] // accum)
+                                    + x.shape[1:]), batch)
+
+            def mstep(g_acc, mb):
+                l, g = jax.value_and_grad(
+                    lambda p: lm.loss_fn(p, cfg, mb))(params)
+                g_acc = jax.tree.map(
+                    lambda a, gi: a + gi.astype(jnp.float32), g_acc, g)
+                return g_acc, l
+
+            g0 = jax.tree.map(
+                lambda p: jnp.zeros(p.shape, jnp.float32), params)
+            grads, losses = jax.lax.scan(mstep, g0, micro)
+            grads = jax.tree.map(lambda g: g / accum, grads)
+            loss = jnp.mean(losses)
+        params, opt_state = opt.update(grads, opt_state, params)
+        return params, opt_state, {"loss": loss}
+
+    return train_step, opt
 
 
 def main(argv=None):
@@ -42,7 +76,7 @@ def main(argv=None):
     enable_compile_cache()
 
     cfg = configs.get_config(args.arch, smoke=args.smoke)
-    train_step, opt = ST.make_train_step(cfg, lr=args.lr)
+    train_step, opt = make_train_step(cfg, lr=args.lr)
     train_step = jax.jit(train_step, donate_argnums=(0, 1))
 
     key = jax.random.PRNGKey(0)

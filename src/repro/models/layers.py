@@ -7,10 +7,9 @@ B=batch, S=query seq, T=kv seq, D=d_model, N=q heads, K=kv heads,
 G=N//K (GQA group), H=head_dim, F=d_ff, E=experts, C=capacity.
 
 Attention is computed in query chunks with the softmax row kept full —
-O(chunk * T) live memory instead of O(S * T) — which is what lets the
-32k-prefill cells fit during the dry-run. The Pallas flash-attention
-kernel (repro.kernels.flash_attention) replaces the inner chunk loop on
-TPU when ``cfg.use_pallas`` is set.
+O(chunk * T) live memory instead of O(S * T). The Pallas flash-attention
+kernel (repro.kernels.flash_attention) replaces the chunk loop on TPU
+when ``cfg.use_pallas`` is set.
 """
 from __future__ import annotations
 
@@ -22,14 +21,13 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from repro.sharding.rules import ShardingCtx, INERT
-
 
 # ---------------------------------------------------------------------------
 # Param schema plumbing.
 # ---------------------------------------------------------------------------
 class ParamSpec:
-    """Declarative parameter: shape + logical axes + initializer."""
+    """Declarative parameter: shape + logical axes + initializer.
+    `axes` names each dim; no program code reads it."""
 
     __slots__ = ("shape", "axes", "init", "dtype")
 
@@ -76,14 +74,10 @@ def abstract_tree(schema, dtype):
         schema, is_leaf=is_spec)
 
 
-def axes_tree(schema):
-    return jax.tree.map(lambda s: s.axes, schema, is_leaf=is_spec)
-
-
-def stack_specs(schema, n, axis_name="layers"):
+def stack_specs(schema, n):
     """Prefix every spec with a stacked leading dim (for scan-over-layers)."""
     return jax.tree.map(
-        lambda s: ParamSpec((n,) + s.shape, (axis_name,) + s.axes, s.init,
+        lambda s: ParamSpec((n,) + s.shape, ("layers",) + s.axes, s.init,
                             s.dtype),
         schema, is_leaf=is_spec)
 
@@ -99,8 +93,7 @@ def rms_norm(x, p, eps):
     """RMSNorm with fp32 statistics but no materialized fp32 activation:
     the fp32 square fuses into the variance reduce, and the normalization
     multiply stays in the input dtype. (A full fp32 intermediate on the
-    residual path doubles the SP-boundary all-gather bytes — GSPMD
-    gathers whatever tensor feeds the projections.)"""
+    residual path doubles the bytes the norm moves.)"""
     dt = x.dtype
     var = jnp.mean(jnp.square(x.astype(jnp.float32)), axis=-1,
                    keepdims=True)
@@ -153,13 +146,10 @@ def _soft_cap(scores, cap):
 
 
 def _chunked_attn(q, k, v, q_positions, kv_positions, *, causal, window,
-                  softcap, chunk, shard: ShardingCtx):
+                  softcap, chunk):
     """q, k, v: (B,S|T,N,H) with kv already expanded to N heads.
 
-    Query-chunked (full softmax row per chunk): O(chunk*T) live memory —
-    what lets prefill_32k compile within HBM without the Pallas kernel.
-    Flat head layout (no (K,G) split) keeps GSPMD on the standard
-    attention partitioning path (heads on `model`).
+    Query-chunked (full softmax row per chunk): O(chunk*T) live memory.
     """
     B, S, N, H = q.shape
     T = k.shape[1]
@@ -193,8 +183,7 @@ def _chunked_attn(q, k, v, q_positions, kv_positions, *, causal, window,
     return out.transpose(1, 0, 2, 3, 4).reshape(B, S, N, H)
 
 
-def attention(p, x, cfg, *, kind, shard: ShardingCtx = INERT,
-              cond=None, positions=None):
+def attention(p, x, cfg, *, kind, cond=None, positions=None):
     """Self / sliding-window / cross attention. x: (B,S,D) -> (B,S,D)."""
     from repro.common import config as C
     B, S, D = x.shape
@@ -211,15 +200,6 @@ def attention(p, x, cfg, *, kind, shard: ShardingCtx = INERT,
         q = q + p["bq"]
         k = k + p["bk"]
         v = v + p["bv"]
-    # Inner tensors claim `model` for heads (TP) with priority over seq
-    # (SP): when the head count divides the axis this is plain TP with the
-    # residual stream sequence-sharded at block boundaries (Megatron-SP);
-    # when it does not (24 heads on a 16-way axis), `resolve_spec` frees
-    # the axis and seq claims it — attention runs sequence-parallel
-    # instead of replicated.
-    q = shard(q, "batch", "seq", "heads", "head_dim")
-    k = shard(k, "batch", None, "kv_heads", "head_dim")
-    v = shard(v, "batch", None, "kv_heads", "head_dim")
 
     if positions is None:
         positions = jnp.arange(S)
@@ -228,15 +208,10 @@ def attention(p, x, cfg, *, kind, shard: ShardingCtx = INERT,
         q = rope(q, positions, cfg.rope_theta)
         k = rope(k, kv_positions, cfg.rope_theta)
 
-    # GQA: expand kv to the full head count. The expansion keeps GSPMD on
-    # the plain-attention partitioning path and makes the head dim
-    # shardable even when num_kv_heads < mesh model-axis (e.g. kv=8 on a
-    # 16-way axis); the repeat of a replicated kv shard is local.
+    # GQA: expand kv to the full head count.
     if g > 1:
         k = jnp.repeat(k, g, axis=2)
         v = jnp.repeat(v, g, axis=2)
-    k = shard(k, "batch", None, "heads", "head_dim")
-    v = shard(v, "batch", None, "heads", "head_dim")
     window = cfg.window_size if kind == C.LOCAL_ATTN else None
     if cfg.use_pallas and not cross:
         from repro.kernels.flash_attention import ops as fa_ops
@@ -247,13 +222,11 @@ def attention(p, x, cfg, *, kind, shard: ShardingCtx = INERT,
         out = _chunked_attn(
             q, k, v, positions, kv_positions,
             causal=not cross, window=window, softcap=cfg.logit_softcap,
-            chunk=min(cfg.attn_chunk, S), shard=shard)
-    y = jnp.einsum("bsnh,nhd->bsd", out, p["wo"])
-    return shard(y, "batch", "seq", "embed_act")
+            chunk=min(cfg.attn_chunk, S))
+    return jnp.einsum("bsnh,nhd->bsd", out, p["wo"])
 
 
-def decode_attention(p, x, cfg, *, kind, cache, pos, shard: ShardingCtx = INERT,
-                     cond_kv=None):
+def decode_attention(p, x, cfg, *, kind, cache, pos, cond_kv=None):
     """One-token decode. x: (B,1,D); cache: dict(k,v: (B,L,K,H)).
 
     Returns (y, new_cache). `pos`: (B,) current position per sequence.
@@ -307,8 +280,7 @@ def decode_attention(p, x, cfg, *, kind, cache, pos, shard: ShardingCtx = INERT,
     pr = jax.nn.softmax(s, axis=-1)
     o = jnp.einsum("bkgt,btkh->bkgh", pr, v.astype(jnp.float32))
     o = o.reshape(B, 1, nq, h).astype(x.dtype)
-    y = jnp.einsum("bsnh,nhd->bsd", o, p["wo"])
-    return shard(y, "batch", None, "embed_act"), new_cache
+    return jnp.einsum("bsnh,nhd->bsd", o, p["wo"]), new_cache
 
 
 # ---------------------------------------------------------------------------
@@ -334,16 +306,14 @@ def _act(kind, h):
     return jnp.square(jax.nn.relu(h)) if kind == "relu2" else jax.nn.gelu(h)
 
 
-def mlp(p, x, cfg, shard: ShardingCtx = INERT):
+def mlp(p, x, cfg):
     if cfg.mlp_kind == "swiglu":
         gate = jnp.einsum("bsd,df->bsf", x, p["wi_gate"])
         up = jnp.einsum("bsd,df->bsf", x, p["wi_up"])
         h = jax.nn.silu(gate) * up
     else:
         h = _act(cfg.mlp_kind, jnp.einsum("bsd,df->bsf", x, p["wi"]))
-    h = shard(h, "batch", "seq", "mlp")
-    y = jnp.einsum("bsf,fd->bsd", h, p["wo"])
-    return shard(y, "batch", "seq", "embed_act")
+    return jnp.einsum("bsf,fd->bsd", h, p["wo"])
 
 
 # ---------------------------------------------------------------------------
@@ -372,13 +342,13 @@ def moe_capacity(cfg, group_tokens: int) -> int:
     return max(cap, m.top_k)
 
 
-def moe(p, x, cfg, shard: ShardingCtx = INERT):
+def moe(p, x, cfg):
     """x: (B,S,D). GShard one-hot dispatch with per-group capacity; a
     sigmoid router is the held share of `held_experts`, with no
     auxiliary loss. Returns (y, aux loss)."""
     m = cfg.moe
     if m.router == "sigmoid":
-        return (held_experts(p, x, cfg, shard=shard),
+        return (held_experts(p, x, cfg),
                 jnp.zeros((), jnp.float32))
     B, S, D = x.shape
     gs = min(m.group_size, B * S)
@@ -388,7 +358,6 @@ def moe(p, x, cfg, shard: ShardingCtx = INERT):
     C = moe_capacity(cfg, gs)
 
     xg = x.reshape(ng, gs, D)
-    xg = shard(xg, "batch", None, "embed_act")
     logits = jnp.einsum("gsd,de->gse", xg, p["router"]).astype(jnp.float32)
     probs = jax.nn.softmax(logits, axis=-1)
     gate_vals, expert_idx = lax.top_k(probs, K)       # (ng, gs, K)
@@ -411,17 +380,14 @@ def moe(p, x, cfg, shard: ShardingCtx = INERT):
 
     dispatch = dispatch.astype(x.dtype)
     xe = jnp.einsum("gsec,gsd->gecd", dispatch, xg)    # (ng,E,C,D)
-    xe = shard(xe, "batch", "experts", None, "embed_act")
     if cfg.mlp_kind == "swiglu":
         h = jax.nn.silu(jnp.einsum("gecd,edf->gecf", xe, p["wi_gate"])) \
             * jnp.einsum("gecd,edf->gecf", xe, p["wi_up"])
     else:
         h = jax.nn.gelu(jnp.einsum("gecd,edf->gecf", xe, p["wi"]))
-    h = shard(h, "batch", "experts", None, "mlp")
     ye = jnp.einsum("gecf,efd->gecd", h, p["wo"])      # (ng,E,C,D)
     y = jnp.einsum("gsec,gecd->gsd", combine.astype(x.dtype), ye)
-    y = y.reshape(B, S, D)
-    return shard(y, "batch", "seq", "embed_act"), _aux_loss(probs, onehot)
+    return y.reshape(B, S, D), _aux_loss(probs, onehot)
 
 
 def _aux_loss(probs, onehot):
@@ -597,7 +563,7 @@ def _grouped_bwd(pallas, res, g):
 grouped_matmul.defvjp(_grouped_fwd, _grouped_bwd)
 
 
-def held_experts(p, x, cfg, shard: ShardingCtx = INERT):
+def held_experts(p, x, cfg):
     """This chip's part of a sigmoid-routed expert layer. x: (B,S,D).
 
     Each token is routed over all experts (`route`); every slot routed
@@ -629,4 +595,4 @@ def held_experts(p, x, cfg, shard: ShardingCtx = INERT):
             with jax.named_scope(SHARED_EXPERT):
                 sh = p["shared"]
                 y = y + act(xf @ sh["wi"]) @ sh["wo"]
-    return shard(y.reshape(B, S, D), "batch", "seq", "embed_act")
+    return y.reshape(B, S, D)

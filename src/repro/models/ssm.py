@@ -18,7 +18,6 @@ import jax.numpy as jnp
 from jax import lax
 
 from repro.models.layers import ParamSpec, rms_norm
-from repro.sharding.rules import ShardingCtx, INERT
 
 
 # ===========================================================================
@@ -151,13 +150,13 @@ def gated_norm(y, z, scale, n_groups, eps):
     return g * scale.astype(g.dtype)
 
 
-def mamba2_mix(p, x, cfg, shard: ShardingCtx = INERT):
+def mamba2_mix(p, x, cfg):
     """Full Mamba2 mixing layer. x: (b,s,d) -> (b,s,d)."""
     with jax.named_scope(SSM_MIXER):
-        return _mamba2_mix(p, x, cfg, shard)
+        return _mamba2_mix(p, x, cfg)
 
 
-def _mamba2_mix(p, x, cfg, shard):
+def _mamba2_mix(p, x, cfg):
     s_cfg = cfg.ssm
     b, s, d = x.shape
     d_in, nh, conv_dim = mamba2_dims(cfg)
@@ -174,7 +173,6 @@ def _mamba2_mix(p, x, cfg, shard):
     xi = conv_out[..., :d_in]
     Bm = conv_out[..., d_in:d_in + gn]
     Cm = conv_out[..., d_in + gn:]
-    xi = shard(xi, "batch", "seq", "ssm_inner")
 
     dt = jax.nn.softplus(dt.astype(jnp.float32) + p["dt_bias"])
     A = -jnp.exp(p["A_log"])                           # (nh,)
@@ -191,21 +189,10 @@ def _mamba2_mix(p, x, cfg, shard):
     y = y + xh * p["D"][:, None].astype(y.dtype)
     y = y.reshape(b, s, d_in)
     y = gated_norm(y, z, p["norm"], s_cfg.n_groups, cfg.norm_eps)
-    y = shard(y, "batch", "seq", "ssm_inner")
-    out = jnp.einsum("bsi,id->bsd", y, p["wo"])
-    return shard(out, "batch", "seq", "embed_act")
+    return jnp.einsum("bsi,id->bsd", y, p["wo"])
 
 
-def mamba2_init_state(cfg, batch, dtype):
-    s = cfg.ssm
-    d_in, nh, conv_dim = mamba2_dims(cfg)
-    return {
-        "conv": jnp.zeros((batch, s.conv_width - 1, conv_dim), dtype),
-        "ssm": jnp.zeros((batch, nh, s.head_dim, s.d_state), jnp.float32),
-    }
-
-
-def mamba2_decode(p, x, cfg, state, shard: ShardingCtx = INERT):
+def mamba2_decode(p, x, cfg, state):
     """One-token decode. x: (b,1,d)."""
     s_cfg = cfg.ssm
     b = x.shape[0]
@@ -286,12 +273,11 @@ def _rglru_coeffs(p, u, cfg):
     return a, beta * (i * u)
 
 
-def rglru_mix(p, x, cfg, shard: ShardingCtx = INERT):
+def rglru_mix(p, x, cfg):
     """Griffin recurrent block. x: (b,s,d) -> (b,s,d)."""
     gate = jax.nn.gelu(jnp.einsum("bsd,dw->bsw", x, p["w_gate"]))
     u = jnp.einsum("bsd,dw->bsw", x, p["w_in"])
     u = _causal_conv(u, p["conv_w"], p["conv_b"])
-    u = shard(u, "batch", "seq", "lru_width")
     a, bvec = _rglru_coeffs(p, u.astype(jnp.float32), cfg)
 
     if cfg.use_pallas:
@@ -307,21 +293,10 @@ def rglru_mix(p, x, cfg, shard: ShardingCtx = INERT):
 
         _, h = lax.associative_scan(combine, (a, bvec), axis=1)
     h = h.astype(x.dtype)
-    h = shard(h, "batch", "seq", "lru_width")
-    out = jnp.einsum("bsw,wd->bsd", gate * h, p["wo"])
-    return shard(out, "batch", "seq", "embed_act")
+    return jnp.einsum("bsw,wd->bsd", gate * h, p["wo"])
 
 
-def rglru_init_state(cfg, batch, dtype):
-    w = cfg.rglru.lru_width or cfg.d_model
-    k = cfg.rglru.conv_width
-    return {
-        "conv": jnp.zeros((batch, k - 1, w), dtype),
-        "h": jnp.zeros((batch, w), jnp.float32),
-    }
-
-
-def rglru_decode(p, x, cfg, state, shard: ShardingCtx = INERT):
+def rglru_decode(p, x, cfg, state):
     """One-token decode. x: (b,1,d)."""
     gate = jax.nn.gelu(jnp.einsum("bsd,dw->bsw", x, p["w_gate"]))[:, 0]
     u = jnp.einsum("bsd,dw->bsw", x, p["w_in"])[:, 0]   # (b,w)

@@ -1,13 +1,10 @@
-"""HLO cost-analysis + roofline tests: analytic cross-checks of the
+"""HLO cost-analysis tests: analytic cross-checks of the
 call-graph-weighted FLOP/byte/collective accounting."""
 import textwrap
 
-import numpy as np
 import pytest
 
 from repro.launch import hlo_analysis as HA
-from repro.launch import roofline as RF
-from repro import configs
 
 
 SIMPLE_HLO = textwrap.dedent("""\
@@ -67,52 +64,3 @@ class TestHloAnalysis:
         # per-trip dot traffic: result 256 + 2x operand 256 = 768
         # entry dot: 32768 + 2*256 = 33280 ; entry param io = 2*256
         assert hc.hbm_bytes == pytest.approx(768 * 10 + 33280 + 2 * 256)
-
-
-class TestRooflineTerms:
-    def test_model_flops_train_vs_decode(self):
-        cfg = configs.get_config("phi3-mini-3.8b")
-        from repro.common.config import SHAPES
-        t = RF.model_flops(cfg, SHAPES["train_4k"])
-        d = RF.model_flops(cfg, SHAPES["decode_32k"])
-        n = RF.active_param_count(cfg)
-        assert t == pytest.approx(6 * n * 256 * 4096)
-        assert d == pytest.approx(2 * n * 128)
-
-    def test_moe_active_params_smaller_than_total(self):
-        cfg = configs.get_config("dbrx-132b")
-        from repro.models import lm
-        active = RF.active_param_count(cfg)
-        total = lm.param_count(cfg)
-        # 16 experts top-4 -> expert params scale by 1/4
-        assert active < 0.45 * total
-
-    def test_dominant_term_classification(self):
-        class FakeCompiled:
-            def as_text(self):
-                return SIMPLE_HLO
-            def cost_analysis(self):
-                return {}
-        rl = RF.analyze(FakeCompiled(), n_chips=4, scan_trip_count=10,
-                        model_flops_global=1e6)
-        assert rl.dominant in ("compute", "memory", "collective")
-        assert rl.compute_s >= 0 and rl.collective_s > 0
-
-
-class TestDryrunConsistency:
-    """The committed dry-run artifacts must cover every assigned cell."""
-
-    def test_results_cover_all_cells(self):
-        import json, os
-        path = os.path.join(os.path.dirname(__file__), "..",
-                            "benchmarks", "results", "dryrun.json")
-        if not os.path.exists(path):
-            pytest.skip("dry-run artifacts not generated yet")
-        with open(path) as f:
-            recs = json.load(f)
-        have = {(r["arch"], r["shape"], r["mesh"]) for r in recs}
-        for arch, shape in configs.all_cells():
-            assert (arch, shape, "single") in have, (arch, shape, "single")
-        # multi-pod coverage (filled in by the final sweep)
-        multi = [c for c in have if c[2] == "multi"]
-        assert len(multi) >= 1

@@ -1,7 +1,8 @@
 """Real-training bridge (repro.fl.training): sharded LM client steps
-behind the engine hook protocol, payload-exact egress billing, the
-quantized-update accuracy/egress trade, and step-time calibration
-against the measured-peak roofline."""
+behind the engine hook protocol, the FedAvg barrier's fp32 and int8
+leaf paths, payload-exact egress billing, the quantized-update
+accuracy/egress trade, and step-time calibration against the
+measured-peak roofline."""
 import contextlib
 import dataclasses
 
@@ -98,26 +99,29 @@ class TestMeshTrainerBridge:
 
 
 # ---------------------------------------------------------------------------
-# The FedAvg program's int8 leaf path against the codec, leaf by leaf.
+# The FedAvg program's leaf paths against their definitions, leaf by leaf,
+# and one round of both programs.
 # ---------------------------------------------------------------------------
-@pytest.mark.parametrize("use_pallas", [False, True], ids=["jnp", "pallas"])
-@pytest.mark.parametrize("weights", [[0.6], [0.6, 0.0], [0.25, 0.75]],
-                         ids=["1dev", "2dev-weight0", "2dev"])
-def test_fedavg_int8_leaf_path(weights, use_pallas):
-    """`fedavg` under `quantize=True` gives, bit for bit, old + the sum
-    over slots of wn * dequantize(quantize(new - old)) in fp32, each
-    leaf's delta through the codec whole as before, stored in the leaf's
-    dtype; a weight-0 slot keeps its old momentum."""
+WEIGHTS = pytest.mark.parametrize(
+    "weights", [[0.6], [0.6, 0.0], [0.25, 0.75]],
+    ids=["1dev", "2dev-weight0", "2dev"])
+
+
+def _fedavg_leaves(weights, param_dtype, quantize, use_pallas=False):
+    """`fedavg` of the phi3 smoke model over one slot per weight, each
+    slot's new params its old ones plus 1e-2 noise, with random momenta.
+    Returns the leaves (new, old, wn, got, mu, mu_want): `wn` the
+    normalised weights, `mu_want` the momenta a weight-0 slot keeps."""
     n = len(weights)
     if jax.device_count() < n:
         pytest.skip(f"needs {n} devices, found {jax.device_count()}")
     cfg = dataclasses.replace(configs.get_config("phi3-mini-3.8b",
                                                  smoke=True),
-                              param_dtype="bfloat16")
+                              param_dtype=param_dtype)
     mesh = jax.make_mesh((n,), ("pod",),
                          axis_types=(jax.sharding.AxisType.Auto,),
                          devices=jax.devices()[:n])
-    _, fedavg = make_round_programs(cfg, mesh, lr=5e-3, quantize=True,
+    _, fedavg = make_round_programs(cfg, mesh, lr=5e-3, quantize=quantize,
                                     use_pallas=use_pallas)
     leaves, tree = jax.tree.flatten(lm.init_params(cfg,
                                                    jax.random.PRNGKey(3)))
@@ -130,32 +134,88 @@ def test_fedavg_int8_leaf_path(weights, use_pallas):
     new_mu = [normal(6, i, x) for i, x in enumerate(leaves)]
     w = np.asarray(weights, np.float32)
     wn = w / np.maximum(np.sum(w, dtype=np.float32), np.float32(1e-12))
-
-    want = []
-    for nl, ol in zip(new, old):
-        avg = None
-        for c in range(n):
-            d = nl[c].astype(jnp.float32) - ol[c].astype(jnp.float32)
-            term = wn[c] * gq.dequantize(*gq.quantize(d), d.shape)
-            avg = term if avg is None else avg + term
-        want.append((ol[0].astype(jnp.float32) + avg).astype(ol.dtype))
     mu_want = [np.where(w.reshape((-1,) + (1,) * (m.ndim - 1)) > 0,
                         np.asarray(m), np.asarray(o))
                for m, o in zip(new_mu, old_mu)]
 
     stk = NamedSharding(mesh, P("pod"))
-    put = lambda xs: jax.device_put(tree.unflatten(xs), stk)
+    # host copies: `fedavg` donates its new params and momenta
+    put = lambda xs: jax.device_put(
+        tree.unflatten([np.asarray(x) for x in xs]), stk)
     interpret = (pltpu.force_tpu_interpret_mode() if use_pallas
                  else contextlib.nullcontext())
     with interpret:
         got, mu = fedavg(put(new), put(old), put(new_mu), put(old_mu),
                          jax.device_put(w, stk))
-    bits = lambda a: np.ascontiguousarray(np.asarray(a)).view(np.uint8)
-    for g, want_leaf in zip(jax.tree.leaves(got), want):
-        for c in range(n):
-            np.testing.assert_array_equal(bits(g[c]), bits(want_leaf))
-    for m, m_want in zip(jax.tree.leaves(mu), mu_want):
-        np.testing.assert_array_equal(bits(m), bits(m_want))
+    return (new, old, wn, jax.tree.leaves(got), jax.tree.leaves(mu),
+            mu_want)
+
+
+def _bits(a):
+    return np.ascontiguousarray(np.asarray(a)).view(np.uint8)
+
+
+@pytest.mark.parametrize("use_pallas", [False, True], ids=["jnp", "pallas"])
+@WEIGHTS
+def test_fedavg_int8_leaf_path(weights, use_pallas):
+    """`fedavg` under `quantize=True` gives, bit for bit, old + the sum
+    over slots of wn * dequantize(quantize(new - old)) in fp32, each
+    leaf's delta through the codec whole as before, stored in the leaf's
+    dtype; a weight-0 slot keeps its old momentum."""
+    new, old, wn, got, mu, mu_want = _fedavg_leaves(
+        weights, "bfloat16", quantize=True, use_pallas=use_pallas)
+    for nl, ol, g in zip(new, old, got):
+        avg = None
+        for c in range(len(weights)):
+            d = nl[c].astype(jnp.float32) - ol[c].astype(jnp.float32)
+            term = wn[c] * gq.dequantize(*gq.quantize(d), d.shape)
+            avg = term if avg is None else avg + term
+        want = (ol[0].astype(jnp.float32) + avg).astype(ol.dtype)
+        for c in range(len(weights)):
+            np.testing.assert_array_equal(_bits(g[c]), _bits(want))
+    for m, m_want in zip(mu, mu_want):
+        np.testing.assert_array_equal(_bits(m), _bits(m_want))
+
+
+@WEIGHTS
+def test_fedavg_fp32_leaf_path(weights):
+    """`fedavg` under `quantize=False` gives old + the sum over slots of
+    wn * (new - old) in fp32, within fp32 rounding, bit-identical in
+    every slot; a weight-0 slot keeps its old momentum."""
+    new, old, wn, got, mu, mu_want = _fedavg_leaves(
+        weights, "float32", quantize=False)
+    for nl, ol, g in zip(new, old, got):
+        want = ol[0] + sum(wn[c] * (nl[c] - ol[c])
+                           for c in range(len(weights)))
+        assert g.dtype == jnp.float32
+        np.testing.assert_allclose(np.asarray(g[0]), np.asarray(want),
+                                   rtol=1e-6, atol=1e-7)
+        for c in range(1, len(weights)):
+            np.testing.assert_array_equal(_bits(g[c]), _bits(g[0]))
+    for m, m_want in zip(mu, mu_want):
+        np.testing.assert_array_equal(_bits(m), _bits(m_want))
+
+
+@pytest.mark.parametrize("quantize", [False, True], ids=["fp32", "int8"])
+def test_round_programs_sync_barrier(quantize):
+    """One round of the phi3 smoke model on two devices, weights 3:1:
+    after `local_round` then `fedavg` every slot holds the identical
+    model, training moved it, and the losses are finite."""
+    if jax.device_count() < 2:
+        pytest.skip(f"needs 2 devices, found {jax.device_count()}")
+    hooks = MeshTrainerHooks(NAMES, local_steps=2, batch=2, seq=16,
+                             lr=1e-2, quantize=quantize)
+    before = [np.asarray(l) for l in jax.tree.leaves(hooks.params_stk)]
+    new_p, new_mu, losses = hooks.local_round(hooks.next_batches())
+    params, _ = hooks.fedavg(new_p, new_mu, [3.0, 1.0])
+    losses = np.asarray(losses)
+    assert losses.shape == (2, 2)
+    assert np.all(np.isfinite(losses))
+    after = [np.asarray(l) for l in jax.tree.leaves(params)]
+    for leaf in after:
+        np.testing.assert_array_equal(leaf[0], leaf[1])
+    assert any(not np.array_equal(a[0], b[0])
+               for a, b in zip(after, before))
 
 
 # ---------------------------------------------------------------------------
